@@ -37,13 +37,18 @@ TEMPLATE_NAMES = tuple(
 def _child(template_names) -> None:
     """One process's measurement: compile + first execution per template,
     twice (the second pass is the warm_memory figure), plus store stats.
-    Prints one JSON object to stdout."""
-    from repro.core import CompileCache
+    Prints one JSON object to stdout.
+
+    JAX's own persistent compilation cache is off here: a warm one
+    would turn "cold" into a cache read, and would serve "warm_disk"
+    whatever the Flare store missed."""
+    import jax
+    jax.config.update("jax_enable_compilation_cache", False)
+    import jax.numpy as jnp
+
     from repro.core.dataframe import FlareContext
     from repro.persist import store as PS
     from repro.relational import queries as Q
-
-    import jax.numpy as jnp
 
     ctx = FlareContext()
     Q.register_tpch(ctx, sf=SF)

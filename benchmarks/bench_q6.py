@@ -178,4 +178,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
-    main()
+    from benchmarks.common import entry
+    entry(main)

@@ -132,4 +132,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
-    run()
+    from benchmarks.common import entry
+    entry(run)

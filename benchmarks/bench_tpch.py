@@ -129,4 +129,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
-    main()
+    from benchmarks.common import entry
+    entry(main)

@@ -70,6 +70,14 @@ def time_call(fn: Callable, *, warmup: int = 1, iters: int = 5,
     return Timing(times[len(times) // 2] * 1e6, i, cap_hit, t_total)
 
 
+def entry(main: Callable[[], Any]) -> Any:
+    """Run a benchmark's ``main`` as a program: with JAX's persistent
+    compilation cache on (:mod:`repro.persist.xla_cache`)."""
+    from repro.persist.xla_cache import enable_jax_compile_cache
+    enable_jax_compile_cache()
+    return main()
+
+
 def emit(name: str, us: float, **derived) -> str:
     if isinstance(us, Timing):
         derived.setdefault("iters", us.iters)

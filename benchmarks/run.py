@@ -1,6 +1,6 @@
 """Benchmark harness: one module per paper table/figure.
 
-    PYTHONPATH=src python -m benchmarks.run [--only q6,join,...] [--sf 0.05]
+    PYTHONPATH=src:. python -m benchmarks.run [--only q6,join,...] [--sf 0.05]
 
 Prints ``name,us_per_call,derived`` CSV.  Modules:
 
@@ -8,19 +8,30 @@ Prints ``name,us_per_call,derived`` CSV.  Modules:
     join      Fig 6     join strategy comparison
     tpch      Fig 9     TPC-H suite across engines + compile times
     loading   Table 1   CSV generic/compiled + flarecol (+projection)
-    scaling   Fig 11/12 mesh-parallel relational scaling (subprocesses)
+    scaling   Fig 11/12 mesh-parallel relational scaling (device subsets)
     ml        Fig 8/13/14  heterogeneous ETL+ML fused vs staged
     roofline  (g)       roofline terms from the dry-run artifacts
+
+Each module runs in a process of its own, one after the other, and
+this parent never imports JAX: an accelerator belongs to one process
+at a time, so a parent that held it would starve its children.  The
+children share JAX's persistent compilation cache
+(:mod:`repro.persist.xla_cache`).
 """
 from __future__ import annotations
 
 import argparse
 import importlib
 import os
+import subprocess
 import sys
-import traceback
 
 MODULES = ["q6", "join", "tpch", "loading", "scaling", "ml", "roofline"]
+
+
+def _module_path(name: str) -> str:
+    return ("benchmarks.roofline" if name == "roofline"
+            else f"benchmarks.bench_{name}")
 
 
 def main() -> None:
@@ -29,29 +40,31 @@ def main() -> None:
                     help="comma-separated subset of " + ",".join(MODULES))
     ap.add_argument("--sf", type=float, default=None,
                     help="TPC-H scale factor (default 0.05)")
+    ap.add_argument("--module", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args()
+    if args.module:
+        run_module(args.module)
+        return
+    env = dict(os.environ)
     if args.sf is not None:
-        os.environ["BENCH_SF"] = str(args.sf)
+        env["BENCH_SF"] = str(args.sf)
 
     names = (args.only.split(",") if args.only else MODULES)
-    print("name,us_per_call,derived")
+    print("name,us_per_call,derived", flush=True)
     failures = 0
     for name in names:
-        modname = ("benchmarks.roofline" if name == "roofline"
-                   else f"benchmarks.bench_{name}")
-        try:
-            mod = importlib.import_module(modname)
-            mod.run()
-        except Exception:
+        rc = subprocess.run([sys.executable, "-m", "benchmarks.run",
+                             "--module", name], env=env).returncode
+        if rc != 0:
             failures += 1
             print(f"{name},-1.0,error=1", flush=True)
-            traceback.print_exc()
     if failures:
         sys.exit(1)
 
 
 def run_module(name: str) -> None:
-    importlib.import_module(f"benchmarks.bench_{name}").run()
+    from benchmarks.common import entry
+    entry(importlib.import_module(_module_path(name)).run)
 
 
 if __name__ == "__main__":

@@ -324,24 +324,44 @@ class DeviceCache:
             self._cache[key] = arr
         return arr
 
-    def get_padded(self, tbl: T.Table, name: str, pad_to: int) -> jnp.ndarray:
-        """Column padded with zeros to ``pad_to`` rows, cached per pad
-        length.  The sharded ``parallel`` engine row-partitions the spine
-        table across the mesh, so its columns must be padded to a
-        multiple of the shard count; padding rows are masked off inside
-        the program (repro.core.parallel)."""
-        n = tbl.num_rows
-        if pad_to == n:
-            return self.get(tbl, name)
-        if pad_to < n:
-            raise ValueError(f"pad_to {pad_to} < table rows {n}")
-        key = (id(tbl), name, pad_to)
+    def get_placed(self, tbl: T.Table, name: str, sharding: Any,
+                   pad_to: Optional[int] = None) -> jax.Array:
+        """Column placed with ``sharding`` once, zero-padded to
+        ``pad_to`` rows, cached per (table, column, pad, sharding) --
+        the sharding names its mesh, so each mesh keeps its own copy.
+        The sharded ``parallel`` engine row-partitions its spine table
+        across the mesh (``P(axis)``, padded to a multiple of the shard
+        count; padding rows are masked off inside the program) and
+        replicates the other tables (``P()``): placing them here once
+        keeps every execution from scattering them from one device."""
+        key = (id(tbl), name, pad_to, sharding)
         arr = self._cache.get(key)
         if arr is None:
-            arr = jnp.asarray(np.pad(np.asarray(tbl[name]),
-                                     (0, pad_to - n)))
+            host = np.asarray(tbl[name])
+            if pad_to is not None:
+                if pad_to < tbl.num_rows:
+                    raise ValueError(f"pad_to {pad_to} < table rows "
+                                     f"{tbl.num_rows}")
+                host = np.pad(host, (0, pad_to - tbl.num_rows))
+            arr = jax.device_put(host, sharding)
             self._cache[key] = arr
         return arr
+
+    def place(self, arr: jax.Array, sharding: Any) -> jax.Array:
+        """A device array (a cached join index) placed with
+        ``sharding`` once.  The entry keeps ``arr`` alive, so its id
+        cannot be reused while the entry exists."""
+        key = ("placed", id(arr), sharding)
+        hit = self._cache.get(key)
+        if hit is None or hit[0] is not arr:
+            hit = (arr, jax.device_put(arr, sharding))
+            self._cache[key] = hit
+        return hit[1]
+
+    def placements(self) -> List[Tuple[str, Optional[int], Any]]:
+        """(column, pad_to, sharding) of every column placed by
+        :meth:`get_placed`."""
+        return [(k[1], k[2], k[3]) for k in self._cache if len(k) == 4]
 
     def get_index(self, tbl: T.Table, key_cols: Tuple[str, ...],
                   doms: Tuple[int, ...] = ()) -> JoinIndex:

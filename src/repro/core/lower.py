@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import expr as E
+from repro.core import ml as ML
 from repro.core import plan as P
 from repro.relational import table as T
 
@@ -601,10 +602,9 @@ def _lower_aggregate(p: P.Aggregate, child: Stream, catalog: P.Catalog,
         if jnp.issubdtype(v.dtype, jnp.integer) and a.op in ("sum", "avg"):
             v = v.astype(jnp.float32)
         if a.op == "sum":
-            cols[a.name] = jax.ops.segment_sum(masked(v), code,
-                                               num_segments=domain)
+            cols[a.name] = ML.segment_sum(masked(v), code, domain)
         elif a.op == "avg":
-            s_ = jax.ops.segment_sum(masked(v), code, num_segments=domain)
+            s_ = ML.segment_sum(masked(v), code, domain)
             cols[a.name] = s_ / jnp.maximum(cnt, 1).astype(s_.dtype)
         elif a.op == "min":
             cols[a.name] = jax.ops.segment_min(

@@ -60,8 +60,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import engines as ENG
 from repro.core import expr as E
@@ -458,6 +457,8 @@ class _ParallelArtifact:
     schema: T.Schema
     pad_to: int
     jax_lowered: Any                 # jax.stages.Lowered
+    mesh: Mesh
+    axis: str
 
 
 class ParallelEngine:
@@ -523,13 +524,13 @@ class ParallelEngine:
         schema = p.schema(catalog)
         # everything after the merge/gather is replicated
         out_specs = ({name: P() for name in schema.names}, P())
-        wrapped = shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
-                            out_specs=out_specs, check_rep=False)
+        wrapped = jax.shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
+                                out_specs=out_specs, check_vma=False)
         jax_lowered = jax.jit(wrapped).lower(*avals)
         return _ParallelArtifact(wrapped, tuple(layout),
                                  tuple(index_layout), tuple(avals),
                                  tuple(param_specs), out_info, schema,
-                                 pad_to, jax_lowered)
+                                 pad_to, jax_lowered, mesh, axis)
 
     def compiler_ir(self, artifact: _ParallelArtifact,
                     dialect: Optional[str] = None) -> Any:
@@ -547,15 +548,22 @@ class ParallelEngine:
         out_info, schema, pad_to = (artifact.out_info, artifact.schema,
                                     artifact.pad_to)
 
+        # inputs are placed on the mesh once, in the device cache
+        row_sharded = NamedSharding(artifact.mesh, P(artifact.axis))
+        replicated = NamedSharding(artifact.mesh, P())
+
         def run(catalog: PL.Catalog, device_cache: ENG.DeviceCache,
                 params: Optional[Dict[str, Any]]) -> L.Result:
             args = []
             for tname, names, is_spine in layout:
                 tbl = catalog.table(tname)
                 for n in names:
-                    args.append(device_cache.get_padded(tbl, n, pad_to)
-                                if is_spine else device_cache.get(tbl, n))
-            args.extend(S.index_args(index_layout, catalog, device_cache))
+                    args.append(
+                        device_cache.get_placed(tbl, n, row_sharded, pad_to)
+                        if is_spine else
+                        device_cache.get_placed(tbl, n, replicated))
+            args.extend(device_cache.place(a, replicated) for a in
+                        S.index_args(index_layout, catalog, device_cache))
             for s, dt in zip(specs, pdtypes):
                 args.append(jnp.asarray(ENG.require_param(params, s), dt))
             out_cols, mask = exe(*args)

@@ -3,7 +3,7 @@
 Each kernel lives in its own subpackage:
 
 * ``filter_agg``        -- the paper's TPC-H Q6 fused scan (Fig. 3),
-* ``segmented_reduce``  -- grouped aggregation as one-hot MXU matmul (Q1),
+* ``segmented_reduce``  -- grouped aggregation over dense group codes (Q1),
 * ``flash_attention``   -- blocked online-softmax attention (LM prefill),
 * ``decode_attention``  -- single-token GQA attention over a long KV cache.
 
@@ -11,7 +11,7 @@ Layout per subpackage: ``kernel.py`` (pl.pallas_call + BlockSpec),
 ``ops.py`` (jit'd public wrapper with padding/fallback), ``ref.py``
 (pure-jnp oracle used by the allclose sweep tests).
 
-Kernels execute with ``interpret=True`` on CPU (this container) and
+Kernels execute with ``interpret=True`` on CPU and
 compile natively on TPU; ``ops`` picks the mode from the backend via
 :func:`should_interpret` -- the ONE place the fallback policy lives
 (the native dispatch pass uses it too).

@@ -21,37 +21,38 @@ Accumulation:
 
 * keyless -- per-output [1, 128] lane partial sums (the
   ``filter_agg`` scheme), final lane-reduce in the caller;
-* grouped, ``accum="onehot"`` -- the ``segmented_reduce`` one-hot MXU
-  scheme, group domains up to MAX_GROUPS, with "max" rows for the FD
-  ``any_`` carry-along;
+* grouped, ``accum="onehot"`` -- the ``segmented_reduce`` per-group
+  membership-mask accumulator, group domains up to MAX_GROUPS, with
+  "max" rows for the FD ``any_`` carry-along;
 * grouped, ``accum="scatter"`` -- ``.at[].add/.max`` into the
-  [n_out, G] accumulator, for group domains far beyond the one-hot
-  VMEM budget (TPC-H Q3 groups by l_orderkey: ~15k groups at SF 0.01).
-  Scatter is hostile to the TPU vector memory model, so this path is
-  *interpret-mode only* (eligibility in ``repro.native.patterns``
-  enforces it); on real TPUs such fragments keep the generic lowering.
+  [n_out, G] accumulator, for group domains far beyond the dense
+  accumulator (TPC-H Q3 groups by l_orderkey: ~15k groups at SF 0.01).
 
-The in-kernel binary search (``probe_sorted``) and payload gathers use
-``jnp.searchsorted``/``jnp.take``; Mosaic support for dynamic gathers
-is the TPU-native caveat here -- this container exercises the kernels
-in interpret mode, where both are exact and fast.
+Interpret mode only.  The in-kernel binary search (``probe_sorted``)
+and the payload gathers are data-dependent gathers from the flattened
+build arrays (``jnp.searchsorted``/``jnp.take``), which Mosaic cannot
+lower (``NotImplementedError: not a fori_loop index``), and so is the
+scatter accumulator.  The ``join-probe`` pattern therefore declares a
+``pallas_refusal`` (``repro.native.patterns``): on a TPU dispatch
+records the fallback with that reason and the fragment keeps the
+generic lowering.
 """
 from __future__ import annotations
 
 from typing import Callable, List, Optional, Sequence, Tuple
-
-import numpy as np
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.segmented_reduce import kernel as SR_K
+
 LANES = 128
 DEFAULT_BLOCK_ROWS = 256
 
 #: Scatter-accumulated group domains are bounded only by the [n_out, G]
-#: accumulator, not a one-hot tile; this is a sanity backstop.
+#: accumulator, not the dense VMEM one; this is a sanity backstop.
 SCATTER_MAX_GROUPS = 1 << 20
 
 
@@ -212,7 +213,41 @@ def join_probe_agg(body_fn: BodyFn, probe_cols: Sequence[jnp.ndarray],
             f"join_probe: ops {ops!r} must be {n_out} entries drawn "
             "from {'sum', 'max'}")
     fills = tuple(fills) if fills is not None else (0.0,) * n_out
-    max_rows = [j for j, op in enumerate(ops) if op == "max"]
+
+    zero_map = ((lambda i, s: (0, 0)) if slab_rows is None
+                else (lambda b, i, s: (0, 0)))
+    if accum == "onehot":
+        def kern(scal_ref, *refs):
+            p_refs = refs[:n_probe]
+            b_refs = refs[n_probe:n_probe + n_build]
+            o_ref = refs[n_probe + n_build]
+            first, _ = _edges()
+
+            @pl.when(first)
+            def _init():
+                SR_K.init_groups(o_ref, ops, fills)
+
+            vals, codes = body_fn(scal_ref, [r[...] for r in p_refs],
+                                  [r[...] for r in b_refs])
+            assert len(vals) == n_out, (len(vals), n_out)
+            SR_K.accumulate_groups(o_ref, vals, codes, ops, fills)
+
+        acc_shape = (num_groups, n_out, LANES)
+        acc_map = ((lambda i, s: (0, 0, 0)) if slab_rows is None
+                   else (lambda b, i, s: (0, 0, 0)))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[pspec] * n_probe + bspecs,
+            out_specs=pl.BlockSpec(acc_shape, acc_map),
+        )
+        acc = pl.pallas_call(
+            kern,
+            out_shape=jax.ShapeDtypeStruct(acc_shape, jnp.float32),
+            grid_spec=grid_spec,
+            interpret=interpret,
+        )(scal, *probe_cols, *build_arrays)
+        return SR_K.reduce_lanes(acc, ops)
 
     def kern(scal_ref, *refs):
         p_refs = refs[:n_probe]
@@ -234,38 +269,20 @@ def join_probe_agg(body_fn: BodyFn, probe_cols: Sequence[jnp.ndarray],
         assert len(vals) == n_out, (len(vals), n_out)
         flat_v = jnp.stack([v.reshape(-1) for v in vals])   # [n_out, N]
         flat_c = codes.reshape(-1)                          # [N] int32
-        if accum == "onehot":
-            flat_sum = jnp.stack([v.reshape(-1) if op == "sum"
-                                  else jnp.zeros_like(v.reshape(-1))
-                                  for v, op in zip(vals, ops)])
-            onehot = (jax.lax.broadcasted_iota(
-                jnp.int32, (flat_c.shape[0], num_groups), 1)
-                == flat_c[:, None])
-            acc = acc_ref[...] + jnp.dot(
-                flat_sum, onehot.astype(jnp.float32),
-                preferred_element_type=jnp.float32)
-            for j in max_rows:
-                masked = jnp.where(onehot, flat_v[j][:, None],
-                                   jnp.float32(fills[j]))
-                acc = acc.at[j].set(jnp.maximum(acc[j],
-                                                jnp.max(masked, axis=0)))
-        else:
-            acc = acc_ref[...]
-            for j, op in enumerate(ops):
-                row = acc[j]
-                if op == "sum":
-                    row = row.at[flat_c].add(flat_v[j])
-                else:
-                    row = row.at[flat_c].max(flat_v[j])
-                acc = acc.at[j].set(row)
+        acc = acc_ref[...]
+        for j, op in enumerate(ops):
+            row = acc[j]
+            if op == "sum":
+                row = row.at[flat_c].add(flat_v[j])
+            else:
+                row = row.at[flat_c].max(flat_v[j])
+            acc = acc.at[j].set(row)
         acc_ref[...] = acc
 
         @pl.when(last)
         def _flush():
             o_ref[...] = acc_ref[...]
 
-    zero_map = ((lambda i, s: (0, 0)) if slab_rows is None
-                else (lambda b, i, s: (0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,

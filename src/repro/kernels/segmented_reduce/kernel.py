@@ -1,26 +1,30 @@
-"""Grouped aggregation as a one-hot MXU matmul (TPC-H Q1 hot loop).
+"""Grouped aggregation over dense group codes (TPC-H Q1 hot loop).
 
 CPU Flare aggregates Q1 with a tiny hash table updated per row.  Scatter
 into a hash table is hostile to the TPU memory model; the TPU-native
-formulation turns the scatter into dense compute:
+formulation turns the scatter into dense, predicated compute over the
+lane-aligned ``[rows, 128]`` blocks the scan already streams:
 
     out[g] = sum_i  values[i] * [codes[i] == g]
 
-i.e. ``values_block @ one_hot(codes_block, G)`` -- an MXU matmul against a
-one-hot matrix materialised *in VMEM per block*.  For the tiny group
-domains of dictionary-encoded keys (Q1: 3x2 groups), this turns a
-memory-bound scatter into a compute trivially served by the systolic
-array, and partial results accumulate in a (1, G) f32 scratch across the
-grid.
+For each group ``g`` the block's membership mask ``codes == g`` selects
+the values, a sublane reduction folds the block to one ``[1, 128]``
+lane-partial row, and that row accumulates into a ``[G, n_out, 128]``
+block that stays resident in VMEM across the grid.  The caller reduces
+the 128 lanes once at the end.  The block is never flattened: Mosaic
+cannot relayout ``[rows, 128]`` into ``[rows * 128]`` (the shape cast a
+flat ``[N] x [N, G]`` one-hot matmul would need), while masks, selects
+and sublane reductions keep the native ``(8, 128)`` tiling.  The mask
+is exact f32 arithmetic, so the answer does not depend on matmul
+precision either.
 
-VMEM: with block_rows=256 the one-hot tile is 256*128*G f32; G<=64 keeps
-it at 8 MiB -- inside budget.  ops.py enforces/falls back.
+VMEM: the input blocks, the value blocks of one grid step (live across
+the group loop) and the resident ``[G, n_out, 128]`` accumulator --
+``repro.native.registry.vmem_estimate`` sizes ``block_rows`` for them.
 """
 from __future__ import annotations
 
 from typing import Callable, List, Optional, Sequence
-
-import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -44,30 +48,53 @@ def _check_limits(rows: int, block_rows: int, num_groups: int) -> None:
     if num_groups > MAX_GROUPS:
         raise KernelBudgetError(
             f"segmented_reduce: group domain {num_groups} exceeds the "
-            f"one-hot accumulator limit MAX_GROUPS={MAX_GROUPS}; route "
+            f"dense accumulator limit MAX_GROUPS={MAX_GROUPS}; route "
             "this fragment to the scatter/XLA fallback")
 
 
-def _kernel(vals_ref, codes_ref, o_ref, acc_ref):
-    i = pl.program_id(0)
+def init_groups(o_ref, ops: Sequence[str], fills: Sequence[float]) -> None:
+    """Fill the ``[G, n_out, 128]`` accumulator with each row's identity:
+    0 for "sum" rows, ``fills[j]`` for "max" rows (scalar literals --
+    Pallas kernels must not capture array constants)."""
+    g = o_ref.shape[0]
+    for j, op in enumerate(ops):
+        fill = fills[j] if op == "max" else 0.0
+        o_ref[:, j:j + 1, :] = jnp.full((g, 1, LANES), fill, jnp.float32)
 
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    vals = vals_ref[...]            # [rows, 128] f32
-    codes = codes_ref[...]          # [rows, 128] i32
-    g = acc_ref.shape[1]
-    flat_v = vals.reshape(1, -1)    # [1, rows*128]
-    flat_c = codes.reshape(-1)      # [rows*128]
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, (flat_c.shape[0], g), 1)
-              == flat_c[:, None]).astype(jnp.float32)
-    acc_ref[...] += jnp.dot(flat_v, onehot,
-                            preferred_element_type=jnp.float32)
+def accumulate_groups(o_ref, vals: Sequence[jnp.ndarray],
+                      codes: jnp.ndarray, ops: Sequence[str],
+                      fills: Sequence[float]) -> None:
+    """Fold one block into the resident ``[G, n_out, 128]`` accumulator:
+    row ``j`` of group ``g`` takes the lane partials of ``vals[j]`` over
+    the elements whose code is ``g`` (a sum, or a max for "max" rows).
+    ``vals`` and ``codes`` are ``[block_rows, 128]``; codes outside
+    ``[0, G)`` match no group."""
 
-    @pl.when(i == pl.num_programs(0) - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...]
+    def one_group(g, carry):
+        hit = codes == g
+        for j, (v, op) in enumerate(zip(vals, ops)):
+            if op == "sum":
+                part = jnp.sum(jnp.where(hit, v, 0.0), axis=0,
+                               keepdims=True)
+                o_ref[g, j:j + 1, :] += part
+            else:
+                part = jnp.max(jnp.where(hit, v, jnp.float32(fills[j])),
+                               axis=0, keepdims=True)
+                o_ref[g, j:j + 1, :] = jnp.maximum(o_ref[g, j:j + 1, :],
+                                                   part)
+        return carry
+
+    jax.lax.fori_loop(0, o_ref.shape[0], one_group, 0)
+
+
+def reduce_lanes(acc: jnp.ndarray, ops: Sequence[str]) -> jnp.ndarray:
+    """``[G, n_out, 128]`` lane partials -> ``[n_out, G]`` group values
+    (runs in XLA after the kernel)."""
+    rows = [jnp.max(acc[:, j, :], axis=-1) if op == "max"
+            else jnp.sum(acc[:, j, :], axis=-1)
+            for j, op in enumerate(ops)]
+    return jnp.stack(rows)
 
 
 def segmented_sum(values: jnp.ndarray, codes: jnp.ndarray, num_groups: int,
@@ -76,19 +103,10 @@ def segmented_sum(values: jnp.ndarray, codes: jnp.ndarray, num_groups: int,
     """values/codes: [rows, 128] pre-padded; returns [1, G] group sums.
 
     Padded elements must carry value 0 (any code)."""
-    rows = values.shape[0]
-    _check_limits(rows, block_rows, num_groups)
-    grid = (rows // block_rows,)
-    spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
-    return pl.pallas_call(
-        _kernel,
-        out_shape=jax.ShapeDtypeStruct((1, num_groups), jnp.float32),
-        grid=grid,
-        in_specs=[spec, spec],
-        out_specs=pl.BlockSpec((1, num_groups), lambda i: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((1, num_groups), jnp.float32)],
-        interpret=interpret,
-    )(values, codes)
+    return segmented_multi_sum(
+        lambda scal_ref, blocks, code_block: [blocks[0]], [values], codes,
+        jnp.zeros((1,), jnp.float32), 1, num_groups, block_rows,
+        interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -110,85 +128,57 @@ def segmented_multi_sum(value_fn: ValueFn, cols: Sequence[jnp.ndarray],
                         ) -> jnp.ndarray:
     """Grouped multi-aggregate: ``out[j, g] = sum_i vals_j[i] * [code_i == g]``.
 
-    One one-hot tile per block is shared by all ``n_out`` aggregates --
-    the scatter becomes a single ``[n_out, N] @ [N, G]`` MXU matmul per
-    block (the Q1 hot loop with every sum/count/avg accumulated in one
-    pass).  ``scal`` carries runtime query parameters via scalar
-    prefetch, so prepared templates keep ONE compilation across
-    bindings.  Inputs are [rows, 128] pre-padded blocks (padded elements
-    must carry value 0; out-of-range codes never match a group).
-    Returns [n_out, G] f32 group sums.
+    Every aggregate of the fragment accumulates in the same pass (the
+    Q1 hot loop with every sum/count/avg in one scan).  ``scal``
+    carries runtime query parameters via scalar prefetch, so prepared
+    templates keep ONE compilation across bindings.  Inputs are
+    [rows, 128] pre-padded blocks (padded elements must carry value 0;
+    out-of-range codes never match a group).  Returns [n_out, G] f32
+    group values.
 
     ``ops`` (default all-"sum") picks the per-row accumulator: "sum"
-    rows take the one-hot matmul; "max" rows (the FD ``any_``
-    carry-along: all group members share the value, take the max of the
-    valid ones) reuse the same one-hot tile as a masked per-group max.
-    ``fills[j]`` is the neutral element of a "max" row -- value_fn must
-    emit it for excluded rows, and padded elements must carry it too.
+    rows add; "max" rows (the FD ``any_`` carry-along: all group
+    members share the value, take the max of the valid ones) keep a
+    per-group max under the same membership mask.  ``fills[j]`` is the
+    neutral element of a "max" row -- value_fn must emit it for
+    excluded rows, and padded elements must carry it too.
     """
+    from repro.kernels import KernelBudgetError
     rows = codes.shape[0]
     _check_limits(rows, block_rows, num_groups)
     n_cols = len(cols)
     ops = tuple(ops) if ops is not None else ("sum",) * n_out
-    assert len(ops) == n_out and set(ops) <= {"sum", "max"}, ops
+    if len(ops) != n_out or not set(ops) <= {"sum", "max"}:
+        raise KernelBudgetError(
+            f"segmented_reduce: ops {ops!r} must be {n_out} entries "
+            "drawn from {'sum', 'max'}")
     fills = tuple(fills) if fills is not None else (0.0,) * n_out
-    max_rows = [j for j, op in enumerate(ops) if op == "max"]
 
     def kern(scal_ref, *refs):
         col_refs = refs[:n_cols]
-        code_ref = refs[n_cols]
-        o_ref, acc_ref = refs[n_cols + 1], refs[n_cols + 2]
-        i = pl.program_id(0)
+        code_ref, o_ref = refs[n_cols], refs[n_cols + 1]
 
-        @pl.when(i == 0)
+        @pl.when(pl.program_id(0) == 0)
         def _init():
-            # per-row identity: 0 for sums, the fill for max rows --
-            # built from scalar literals (Pallas kernels must not
-            # capture array constants)
-            acc_ref[...] = jnp.stack(
-                [jnp.full((num_groups,), fills[j] if op == "max"
-                          else 0.0, jnp.float32)
-                 for j, op in enumerate(ops)])
+            init_groups(o_ref, ops, fills)
 
         code_block = code_ref[...]
         vals = value_fn(scal_ref, [r[...] for r in col_refs], code_block)
         assert len(vals) == n_out, (len(vals), n_out)
-        flat_v = jnp.stack([v.reshape(-1) for v in vals])   # [n_out, N]
-        # sum rows contribute through the matmul; max rows zeroed there
-        flat_sum = jnp.stack([v.reshape(-1) if op == "sum"
-                              else jnp.zeros_like(v.reshape(-1))
-                              for v, op in zip(vals, ops)])
-        flat_c = code_block.reshape(-1)                     # [N]
-        onehot = (jax.lax.broadcasted_iota(
-            jnp.int32, (flat_c.shape[0], num_groups), 1)
-            == flat_c[:, None])
-        acc = acc_ref[...] + jnp.dot(
-            flat_sum, onehot.astype(jnp.float32),
-            preferred_element_type=jnp.float32)
-        for j in max_rows:
-            # the one-hot tile doubles as the group-membership mask:
-            # per-group max over the block, folded into the accumulator
-            masked = jnp.where(onehot, flat_v[j][:, None],
-                               jnp.float32(fills[j]))
-            acc = acc.at[j].set(jnp.maximum(acc[j],
-                                            jnp.max(masked, axis=0)))
-        acc_ref[...] = acc
-
-        @pl.when(i == pl.num_programs(0) - 1)
-        def _flush():
-            o_ref[...] = acc_ref[...]
+        accumulate_groups(o_ref, vals, code_block, ops, fills)
 
     spec = pl.BlockSpec((block_rows, LANES), lambda i, s: (i, 0))
+    acc_shape = (num_groups, n_out, LANES)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(rows // block_rows,),
         in_specs=[spec] * (n_cols + 1),
-        out_specs=pl.BlockSpec((n_out, num_groups), lambda i, s: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((n_out, num_groups), jnp.float32)],
+        out_specs=pl.BlockSpec(acc_shape, lambda i, s: (0, 0, 0)),
     )
-    return pl.pallas_call(
+    acc = pl.pallas_call(
         kern,
-        out_shape=jax.ShapeDtypeStruct((n_out, num_groups), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(acc_shape, jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
     )(scal, *cols, codes)
+    return reduce_lanes(acc, ops)

@@ -1,4 +1,4 @@
-"""Public wrapper: padding, VMEM sizing, fallback to jax.ops.segment_sum."""
+"""Public wrapper: padding and block sizing for the grouped-sum kernel."""
 from __future__ import annotations
 
 import functools
@@ -9,7 +9,6 @@ import jax.numpy as jnp
 
 from repro.kernels import should_interpret
 from repro.kernels.segmented_reduce import kernel as K
-from repro.kernels.segmented_reduce.ref import segmented_sum_ref
 
 _should_interpret = should_interpret  # backward-compatible private alias
 
@@ -22,13 +21,13 @@ def segmented_sum(values: jnp.ndarray, codes: jnp.ndarray, num_groups: int,
     """Group sums of 1-D ``values`` by 1-D int ``codes`` in [0, G).
 
     ``interpret=None`` picks the mode from the backend (Pallas interpret
-    everywhere except TPU); pass an explicit bool to force it.
+    everywhere except TPU); pass an explicit bool to force it.  A group
+    domain over ``MAX_GROUPS`` raises
+    :class:`repro.kernels.KernelBudgetError`: the caller keeps the
+    scatter lowering for it (``jax.ops.segment_sum``).
     """
     if interpret is None:
         interpret = should_interpret()
-    if num_groups > K.MAX_GROUPS:
-        # one-hot tile would blow VMEM; scatter path (XLA handles it)
-        return segmented_sum_ref(values, codes, num_groups)
     n = values.shape[0]
     if n < block_rows * K.LANES:
         block_rows = max(1, n // K.LANES)

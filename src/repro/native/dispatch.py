@@ -11,7 +11,9 @@ protocol of ``repro.core.lower`` (``lower_stream`` /
 whole-query XLA program as the surrounding operators.
 
 Off-TPU the emitters run the Pallas kernels in interpret mode
-(automatic fallback, recorded as the decision's ``mode``).
+(recorded as the decision's ``mode``); on a TPU they compile through
+Mosaic (mode ``pallas``), and a pattern that declares a
+``pallas_refusal`` falls back with that reason.
 
 Composition with the sharded ``parallel`` engine: its shard planner
 (``repro.core.parallel.shard_plan``) calls :func:`rewrite_plan` on the
@@ -143,19 +145,19 @@ def rewrite_plan(p: P.Plan, catalog: P.Catalog,
                 frag = pat.matcher(n, catalog, shared)
                 if frag is None:
                     continue
-                if interpret and not pat.supports_interpret:
-                    reasons.append(f"{pat.name}: no interpret-mode "
-                                   "support off-TPU")
+                if not interpret and pat.pallas_refusal:
+                    reasons.append(f"{pat.name}: {pat.pallas_refusal}")
                     continue
                 ok, reason = pat.eligibility(frag, catalog)
                 if not ok:
                     reasons.append(f"{pat.name}: {reason}")
                     continue
                 emitter = pat.emitter(frag, catalog)
+                # patterns passed over on the way stay on the record
                 report.add(R.Decision(pattern=pat.name,
                                       node=n.describe(),
                                       fired=True, mode=mode,
-                                      reason="ok"))
+                                      reason="; ".join(["ok"] + reasons)))
                 OM.REGISTRY.inc("dispatch.fired")
                 OM.REGISTRY.inc(f"dispatch.fired.{pat.name}")
                 sp.set(fired=pat.name, mode=mode)

@@ -10,19 +10,20 @@ dataframe plan fragments onto specialized parallel implementations):
   become *scalar-prefetch* runtime arguments, so a prepared template
   (q6 and friends) stays ONE compilation across bindings.
 * ``grouped-agg``          -- keyed Aggregate over the same prologue,
-  lowered onto the one-hot-matmul segmented reduction
-  (``kernels/segmented_reduce``), multi-aggregate: every
-  sum/count/avg/any accumulates in a single ``[n_out, N] @ [N, G]`` MXU
-  pass over the dense group layout ``lower.py`` already computes (the
-  FD ``any_`` carry-along rides as a masked per-group max sharing the
-  one-hot tile).
+  lowered onto the segmented reduction (``kernels/segmented_reduce``),
+  multi-aggregate: every sum/count/avg/any accumulates in one pass over
+  the dense group layout ``lower.py`` already computes, per-group
+  membership masks into a VMEM-resident accumulator (the FD ``any_``
+  carry-along rides as a masked per-group max).
 * ``join-probe``           -- Aggregate whose boundary is an inner N:1
   join served by the cached build-side index (DESIGN.md section 10):
   binary-search probe + payload gather + residual predicate + partial
   aggregate fuse into ONE Pallas pass (``kernels/join_probe``).  The
   cached sorted keys/permutation enter as whole-array kernel inputs;
-  group domains beyond the one-hot VMEM budget use the interpret-only
-  scatter accumulator (TPC-H q3's ~15k l_orderkey groups).
+  group domains beyond the dense accumulator use a scatter accumulator
+  (TPC-H q3's ~15k l_orderkey groups).  Interpret mode only: the probe
+  is a data-dependent gather Mosaic cannot lower, so on a TPU the
+  pattern is refused with a recorded reason (``pallas_refusal``).
 * ``masked-filter-project`` -- the scalar/grouped shapes sitting
   mid-pipeline (boundary stream carries a validity mask, e.g.
   downstream of a non-inner or non-indexed join): the mask streams into
@@ -49,7 +50,6 @@ import jax.numpy as jnp
 from repro.core import expr as E
 from repro.core import lower as L
 from repro.core import plan as P
-from repro.kernels import should_interpret
 from repro.kernels.filter_agg import kernel as FA_K
 from repro.kernels.filter_agg import ops as FA_OPS
 from repro.kernels.join_probe import kernel as JP_K
@@ -520,7 +520,6 @@ def _analyze_uncached(frag: R.Fragment, catalog: P.Catalog) -> _Analysis:
                     col_names=sorted(comp.cols),
                     param_names=sorted(comp.params))
     n_in = len(out.col_names) + 1  # + validity/mask weight column
-    n_max = sum(1 for op in ops if op == "max")
     if grouped:
         try:
             child_info = L.static_info(frag.root.child, catalog)
@@ -534,9 +533,9 @@ def _analyze_uncached(frag: R.Fragment, catalog: P.Catalog) -> _Analysis:
         out.key_doms = [child_info.cols[k].group_domain
                         for k in frag.root.keys]
         out.block_default = R.choose_block_rows(n_in + 1, n_out,
-                                                out.domain, n_max=n_max)
+                                                out.domain)
         if out.block_default is None:
-            return _Analysis(reason="one-hot tile exceeds VMEM budget")
+            return _Analysis(reason="group accumulator exceeds VMEM budget")
     else:
         out.block_default = R.choose_block_rows(n_in, n_out)
         if out.block_default is None:
@@ -740,8 +739,7 @@ _SLAB_ROWS_DEFAULT = 512  # [slab_rows, 128] build page; halved until it fits
 
 
 def _choose_slab(n_build: int, brows: int, n_in: int, n_out: int,
-                 num_groups: Optional[int] = None, n_max: int = 0,
-                 acc_bytes: int = 0
+                 num_groups: Optional[int] = None, acc_bytes: int = 0
                  ) -> Tuple[Optional[int], Optional[int]]:
     """Largest build-side slab (halving from :data:`_SLAB_ROWS_DEFAULT`,
     floor 1) whose double-buffered HBM->VMEM page plus probe blocks and
@@ -751,7 +749,7 @@ def _choose_slab(n_build: int, brows: int, n_in: int, n_out: int,
     slab = min(_SLAB_ROWS_DEFAULT, max(1, brows // 2))
     while slab >= 1:
         paged = n_build * slab * LANES * 4 * 2  # x2: Pallas double-buffers
-        bd = R.choose_block_rows(n_in, n_out, num_groups, n_max=n_max,
+        bd = R.choose_block_rows(n_in, n_out, num_groups,
                                  resident_bytes=paged + acc_bytes)
         if bd is not None:
             return slab, bd
@@ -819,7 +817,6 @@ def _analyze_probe_uncached(frag: R.Fragment,
     n_build = 1 + (1 if spec.masked else 0) + len(build_cols)
     resident = n_build * b_pad * 4
     n_in = len(probe_cols) + 1  # + validity column
-    n_max = sum(1 for op in ops if op == "max")
     if not grouped:
         out.block_default = R.choose_block_rows(n_in, n_out,
                                                 resident_bytes=resident)
@@ -843,26 +840,19 @@ def _analyze_probe_uncached(frag: R.Fragment,
     if out.domain <= SR_K.MAX_GROUPS:
         out.accum = "onehot"
         out.block_default = R.choose_block_rows(
-            n_in, n_out, out.domain, n_max=n_max, resident_bytes=resident)
+            n_in, n_out, out.domain, resident_bytes=resident)
         if out.block_default is not None:
             return out
         out.slab_rows, out.block_default = _choose_slab(
-            n_build, b_pad // LANES, n_in, n_out, out.domain, n_max=n_max)
+            n_build, b_pad // LANES, n_in, n_out, out.domain)
         if out.block_default is not None:
             return out
         out.slab_rows = None
-        # one-hot spills VMEM: fall through to the scatter path
+        # the dense accumulator spills VMEM: fall through to scatter
     if out.domain > JP_K.SCATTER_MAX_GROUPS:
         return _ProbeAnalysis(reason=(
             f"group domain {out.domain} > SCATTER_MAX_GROUPS "
             f"{JP_K.SCATTER_MAX_GROUPS}"))
-    if not should_interpret():
-        # scatter into the [n_out, G] accumulator is hostile to the TPU
-        # vector memory model; large-domain grouped probes stay on the
-        # generic lowering there (see kernels/join_probe docstring)
-        return _ProbeAnalysis(reason=(
-            f"group domain {out.domain} needs scatter accumulation "
-            "(interpret mode only)"))
     out.accum = "scatter"
     acc_bytes = n_out * out.domain * 4 * 2 + resident
     out.block_default = R.choose_block_rows(n_in, n_out,
@@ -1027,7 +1017,10 @@ R.register_pattern(R.KernelPattern(
 R.register_pattern(R.KernelPattern(
     name="join-probe", matcher=_match_join_probe,
     eligibility=_probe_eligibility, emitter=_emit_join_probe,
-    requires_index=True, custom_lower=True))
+    requires_index=True, custom_lower=True,
+    pallas_refusal=("the in-kernel binary-search probe and payload "
+                    "gathers are data-dependent gathers Mosaic cannot "
+                    "lower (interpret mode only)")))
 R.register_pattern(R.KernelPattern(
     name="masked-filter-project", matcher=_match_masked,
     eligibility=_eligibility, emitter=_emit_masked))

@@ -72,8 +72,10 @@ class KernelPattern:
     ``matcher(node, catalog)`` returns a :class:`Fragment` or None;
     ``eligibility(fragment, catalog)`` returns ``(ok, reason)``;
     ``emitter(fragment, catalog)`` builds the trace-time
-    :data:`Emitter`.  ``supports_interpret`` gates dispatch off-TPU
-    (every built-in pattern runs under Pallas interpret mode there).
+    :data:`Emitter`.  ``pallas_refusal`` says why a pattern whose
+    kernel only runs in Pallas interpret mode cannot compile for a TPU
+    (None: it compiles); dispatch records it as the fallback reason
+    whenever it lowers for the chip.
     """
 
     name: str
@@ -85,7 +87,7 @@ class KernelPattern:
     matcher: Callable[..., Optional[Fragment]]
     eligibility: Callable[[Fragment, P.Catalog], Tuple[bool, str]]
     emitter: Callable[[Fragment, P.Catalog], Emitter]
-    supports_interpret: bool = True
+    pallas_refusal: Optional[str] = None
     #: the pattern probes a cached build-side join index (``join-probe``):
     #: skipped entirely when lowering with ``join_index=False``
     requires_index: bool = False
@@ -126,12 +128,13 @@ def available_patterns() -> List[str]:
 
 def vmem_estimate(n_cols: int, block_rows: int, n_out: int,
                   num_groups: Optional[int] = None,
-                  n_max: int = 0, resident_bytes: int = 0) -> int:
+                  resident_bytes: int = 0) -> int:
     """Bytes of VMEM the kernel's working set needs at ``block_rows``.
 
-    Input blocks are double-buffered (x2); the grouped variant adds the
-    per-block one-hot tile, one masked [N, G] tile per "max" (``any_``)
-    accumulator row, and the [n_out, G] accumulator.
+    Input blocks are double-buffered (x2).  The grouped variant keeps
+    the step's ``n_out`` value blocks and one membership mask live
+    across its group loop, plus the resident ``[G, n_out, 128]``
+    accumulator (an output block, double-buffered; sublanes pad to 8).
     ``resident_bytes`` covers whole-array inputs pinned across grid
     steps (the join-probe kernel's build-side arrays)."""
     block = block_rows * LANES * 4
@@ -139,22 +142,21 @@ def vmem_estimate(n_cols: int, block_rows: int, n_out: int,
     if num_groups is None:
         total += n_out * LANES * 4 * 2          # out + scratch rows
     else:
-        # one-hot tile + one masked-max tile per any_ row
-        total += (1 + n_max) * block_rows * LANES * num_groups * 4
-        total += n_out * num_groups * 4 * 2            # out + scratch
+        total += (n_out + 1) * block
+        total += num_groups * (-(-n_out // 8) * 8) * LANES * 4 * 2
     return total
 
 
 def choose_block_rows(n_cols: int, n_out: int,
                       num_groups: Optional[int] = None,
-                      default: int = 256, n_max: int = 0,
+                      default: int = 256,
                       resident_bytes: int = 0) -> Optional[int]:
     """Largest block_rows (halving from ``default``, floor 8) whose
     working set fits :data:`VMEM_BUDGET_BYTES`; None if even 8 spills."""
     block_rows = default
     while block_rows >= 8:
         if vmem_estimate(n_cols, block_rows, n_out, num_groups,
-                         n_max, resident_bytes) <= VMEM_BUDGET_BYTES:
+                         resident_bytes) <= VMEM_BUDGET_BYTES:
             return block_rows
         block_rows //= 2
     return None
@@ -173,7 +175,7 @@ class Decision:
     node: str      # fragment root, human-readable (plan.describe())
     fired: bool
     mode: str      # "pallas" | "interpret" | "" (fallback)
-    reason: str    # "ok" or why the fragment fell back to jnp lowering
+    reason: str    # "ok" (+ patterns passed over) or why it fell back
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -232,7 +234,9 @@ class DispatchReport:
         lines = ["native dispatch:"] if self.decisions else []
         for d in self.decisions:
             if d.fired:
-                lines.append(f"  + {d.node} -> {d.pattern} [{d.mode}]")
+                lines.append(f"  + {d.node} -> {d.pattern} [{d.mode}]"
+                             + (f" ({d.reason[4:]})" if d.reason != "ok"
+                                else ""))
             else:
                 lines.append(f"  - {d.node} -> jnp fallback ({d.reason})")
         if self.index_decisions:

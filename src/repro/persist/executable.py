@@ -88,8 +88,11 @@ def serialize_compiled(jax_exe: Any) -> Tuple[bytes, List[int]]:
 
 
 def deserialize_native(data: bytes) -> Any:
-    """Load the native tier: a ready LoadedExecutable, no XLA compile."""
-    return _backend().deserialize_executable(data, None)
+    """Load the native tier: a ready LoadedExecutable, no XLA compile.
+    Templates compile for the default device, so that is where the
+    executable loads."""
+    backend = _backend()
+    return backend.deserialize_executable(data, backend.local_devices()[:1])
 
 
 def export_portable(fn: Any, avals: Sequence[Any]

@@ -22,7 +22,7 @@ The allowlist is deliberately closed (:func:`recoverable`):
   the geometry; the plain jnp lowering computes the same answer.
 * persist ``StoreCorrupt`` / ``StoreVersionMiss`` -- a disk artifact
   is untrustworthy; recompiling from source is always correct.
-* XLA compile failure (``XlaRuntimeError`` or the injected
+* XLA compile failure (``jax.errors.JaxRuntimeError`` or the injected
   :class:`repro.resilience.faults.XlaCompileFault`) -- the interpreted
   rungs do not need XLA.
 * :class:`repro.core.parallel.UnsupportedParallelPlan` -- the shard
@@ -87,16 +87,9 @@ def recoverable(err: BaseException) -> bool:
             return True
     except ImportError:  # parallel engine never imported in this process
         pass
-    # a real XLA compile/runtime failure surfaces as jaxlib's
-    # XlaRuntimeError; match by type when importable, by name otherwise
-    try:
-        from jax._src.lib import xla_client as _xc
-        if isinstance(err, _xc.XlaRuntimeError):
-            return True
-    except Exception:
-        if type(err).__name__ == "XlaRuntimeError":
-            return True
-    return False
+    # a real XLA compile/runtime failure surfaces as JaxRuntimeError
+    import jax
+    return isinstance(err, jax.errors.JaxRuntimeError)
 
 
 @dataclasses.dataclass

@@ -45,7 +45,7 @@ class XlaCompileFault(RuntimeError):
     """Injected stand-in for an XLA compilation failure.
 
     The degradation allowlist treats it exactly like a real
-    ``XlaRuntimeError`` escaping ``jax_lowered.compile()``.
+    ``jax.errors.JaxRuntimeError`` escaping ``jax_lowered.compile()``.
     """
 
 

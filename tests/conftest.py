@@ -2,7 +2,6 @@ import os
 import subprocess
 import sys
 
-import numpy as np
 import pytest
 
 # NOTE: no xla_force_host_platform_device_count here -- smoke tests and
@@ -13,6 +12,8 @@ SRC = os.path.join(REPO, "src")
 TESTS = os.path.dirname(os.path.abspath(__file__))
 if SRC not in sys.path:
     sys.path.insert(0, SRC)
+
+from repro.core.compare import assert_results_equal  # noqa: E402,F401
 
 
 def run_with_devices(n_devices: int, code: str, timeout: int = 420) -> str:
@@ -37,33 +38,3 @@ def run_with_devices(n_devices: int, code: str, timeout: int = 420) -> str:
 @pytest.fixture
 def subproc():
     return run_with_devices
-
-
-def assert_results_equal(a, b, rtol=5e-3, atol=1e-6, ordered=True,
-                         msg=""):
-    """Compare two collect() dicts.
-
-    The ONE place result comparison is normalised: columns pass through
-    ``np.atleast_1d(np.asarray(...))`` so 0-d scalars (scalar aggregates
-    like q6/q14, or values that went through a float constructor) never
-    reach ``np.sort(axis=-1)`` -- the fragility class that used to need
-    per-test ``np.asarray`` workarounds.
-    """
-    a = {k: np.atleast_1d(np.asarray(v)) for k, v in a.items()}
-    b = {k: np.atleast_1d(np.asarray(v)) for k, v in b.items()}
-    assert set(a) == set(b), msg
-    for k in a:
-        x, y = a[k], b[k]
-        assert x.shape == y.shape, (msg, k, x.shape, y.shape)
-        if x.dtype == object or y.dtype == object:
-            if ordered:
-                assert list(x) == list(y), (msg, k)
-            else:
-                assert sorted(x) == sorted(y), (msg, k)
-        else:
-            xf = np.atleast_1d(np.asarray(x, dtype=np.float64))
-            yf = np.atleast_1d(np.asarray(y, dtype=np.float64))
-            if not ordered:
-                xf, yf = np.sort(xf), np.sort(yf)
-            np.testing.assert_allclose(xf, yf, rtol=rtol, atol=atol,
-                                       err_msg=f"{msg}/{k}")

@@ -235,6 +235,36 @@ def test_parallel_engine_replicates_build_indexes(ctx):
                          lowered.compile()(), msg="q10 parallel indexed")
 
 
+def test_parallel_engine_places_inputs_once_per_mesh(subproc):
+    """Spine columns are placed row-sharded (``P(axis)``) and build
+    tables/indexes replicated, once per mesh in the device cache: a
+    second execution places nothing new."""
+    out = subproc(4, r"""
+from conftest import assert_results_equal
+from repro.core import FlareContext
+from repro.launch.mesh import make_data_mesh
+from repro.relational import queries as Q
+ctx = FlareContext()
+Q.register_tpch(ctx, sf=0.005)
+ctx.preload()
+q = Q.q10(ctx)
+for n in (4, 2):
+    compiled = q.lower(engine="parallel", mesh=make_data_mesh(n)).compile()
+    assert_results_equal(q.collect(engine="volcano"), compiled())
+    before = len(ctx.cache)
+    compiled()
+    assert len(ctx.cache) == before
+    placed = [(c, p, s) for c, p, s in ctx.cache.placements()
+              if s.mesh.size == n]
+    spine = [c for c, p, s in placed if p is not None]
+    assert spine and all(c.startswith("l_") for c in spine), placed
+    for c, p, s in placed:
+        assert tuple(s.spec) == (("data",) if p is not None else ()), (c, s)
+print("PLACED_OK")
+""")
+    assert "PLACED_OK" in out
+
+
 # The adversarial duplicate/absent-key hypothesis property test lives in
 # tests/test_property.py (test_join_index_cache_adversarial_keys), with
 # the other optional-dep property tests.

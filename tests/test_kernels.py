@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import KernelBudgetError
 from repro.kernels.decode_attention import ops as DA
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.kernels.filter_agg import ops as FA
@@ -50,10 +51,27 @@ def test_filter_agg_empty_predicate():
 def test_segmented_sum_sweep(n, g):
     v = jnp.asarray(RNG.uniform(-5, 5, n), jnp.float32)
     c = jnp.asarray(RNG.integers(0, g, n), jnp.int32)
-    got = SR.segmented_sum(v, c, g)       # g>512 falls back to scatter
+    if g > SR.K.MAX_GROUPS:
+        # no quiet reference fallback: the caller keeps segment_sum
+        with pytest.raises(KernelBudgetError, match="MAX_GROUPS"):
+            SR.segmented_sum(v, c, g)
+        return
+    got = SR.segmented_sum(v, c, g)
     want = segmented_sum_ref(v, c, g)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-3)
+
+
+def test_segmented_sum_over_budget_is_a_kernel_budget_error():
+    """A domain over MAX_GROUPS raises the error dispatch screens for
+    (and the degradation ladder may absorb), never a quiet fallback."""
+    from repro.resilience import degrade
+    n = SR.K.MAX_GROUPS + 1
+    v = jnp.ones((256,), jnp.float32)
+    c = jnp.arange(256, dtype=jnp.int32)
+    with pytest.raises(KernelBudgetError) as err:
+        SR.segmented_sum(v, c, n)
+    assert degrade.recoverable(err.value)
 
 
 @pytest.mark.parametrize("b,h,hkv,s,d", [

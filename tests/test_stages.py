@@ -266,3 +266,23 @@ def test_collect_engine_shim(ctx):
     ctx.execute(s1.plan, "compiled", st1)
     ctx.execute(s2.plan, "compiled", st2)
     assert st2.cache_hit  # context compile cache survives across calls
+
+
+def test_grouped_sum_stays_exact_over_long_groups():
+    """Millions of f32 rows in one group: the compiled grouped sum keeps
+    the float64 answer (a plain scatter-add drifts with group size)."""
+    from repro.core import ml as ML
+    from repro.relational.table import Table
+    rng = np.random.default_rng(5)
+    n = 4_000_000
+    k = rng.integers(0, 2, n).astype(np.int32)
+    v = rng.integers(1, 51, n).astype(np.float32)
+    c = FlareContext()
+    c.register("t", Table.from_arrays({"k": k, "v": v},
+                                      domains={"k": 2}))
+    got = (c.table("t").group_by("k").agg(sum_(col("v"), "s"))
+           .lower(engine="compiled").compile()())
+    want = np.bincount(k, weights=v.astype(np.float64), minlength=2)
+    np.testing.assert_allclose(got["s"], want, rtol=1e-6)
+    direct = ML.segment_sum(v, k, 2)
+    np.testing.assert_allclose(np.asarray(direct), want, rtol=1e-6)
