@@ -34,6 +34,8 @@ import jax.numpy as jnp
 from repro.core import expr as E
 from repro.core import ml as ML
 from repro.core import plan as P
+from repro.obs import export as OX
+from repro.obs import trace as OT
 from repro.relational import table as T
 
 _I32_MAX = np.int32(2 ** 31 - 1)
@@ -477,6 +479,38 @@ def _lower_join(p: P.Join, left: Stream, right: Stream,
                 catalog: P.Catalog,
                 jindex: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None
                 ) -> Stream:
+    """The join's own work under two device names: ``flare:join.probe``
+    (combined keys, the build side's sort when no index is cached,
+    ``searchsorted``, clip, match and validity masks) and
+    ``flare:join.gather`` (the build-side column gathers)."""
+    with OX.kernel_scope("flare:join.probe"):
+        pos, matched, pmask = _join_probe(p, left, right, jindex)
+        if p.how == "semi":
+            return Stream(dict(left.cols), matched,
+                          _join_info(p, left.info, right.info))
+        if p.how == "anti":
+            return Stream(dict(left.cols), pmask & ~matched,
+                          _join_info(p, left.info, right.info))
+
+    with OX.kernel_scope("flare:join.gather"):
+        cols = dict(left.cols)
+        for name in right.cols:
+            if name in p.right_on:
+                continue
+            gathered = right.cols[name][pos]
+            if p.how == "left":
+                gathered = jnp.where(matched, gathered,
+                                     jnp.zeros((), gathered.dtype))
+            cols[name] = gathered
+    mask = matched if p.how == "inner" else pmask
+    return Stream(cols, mask, _join_info(p, left.info, right.info))
+
+
+def _join_probe(p: P.Join, left: Stream, right: Stream,
+                jindex: Optional[Tuple[jnp.ndarray, jnp.ndarray]]
+                ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """(build-side row of each probe row's tentative match, the match
+    mask, the probe side's mask)."""
     strategy = p.strategy or "sorted"
     # --- combined integer keys ------------------------------------------------
     ldoms = [left.info.cols[k].group_domain or int(_I32_MAX) for k in p.left_on]
@@ -523,25 +557,7 @@ def _lower_join(p: P.Join, left: Stream, right: Stream,
     matched = (kb_sorted[idx_c] == kp) & pmask
     if validate_mask is not None:
         matched = matched & validate_mask[pos]
-
-    if p.how == "semi":
-        return Stream(dict(left.cols), matched,
-                      _join_info(p, left.info, right.info))
-    if p.how == "anti":
-        return Stream(dict(left.cols), pmask & ~matched,
-                      _join_info(p, left.info, right.info))
-
-    cols = dict(left.cols)
-    for name in right.cols:
-        if name in p.right_on:
-            continue
-        gathered = right.cols[name][pos]
-        if p.how == "left":
-            gathered = jnp.where(matched, gathered,
-                                 jnp.zeros((), gathered.dtype))
-        cols[name] = gathered
-    mask = matched if p.how == "inner" else pmask
-    return Stream(cols, mask, _join_info(p, left.info, right.info))
+    return pos, matched, pmask
 
 
 def _lower_aggregate(p: P.Aggregate, child: Stream, catalog: P.Catalog,
@@ -668,8 +684,9 @@ def lower_node(p: P.Plan, catalog: P.Catalog, scans: Dict[int, Stream],
         raise KeyError(f"unbound scan {p.table}")
     if isinstance(p, P.Filter):
         child = lower_node(p.child, catalog, scans, params)
-        pred = eval_expr(p.pred, child, params)
-        mask = pred if child.mask is None else (child.mask & pred)
+        with OX.kernel_scope("flare:filter"):
+            pred = eval_expr(p.pred, child, params)
+            mask = pred if child.mask is None else (child.mask & pred)
         return Stream(child.cols, mask, child.info)
     if isinstance(p, P.MapBatches):
         child = lower_node(p.child, catalog, scans, params)
@@ -717,10 +734,12 @@ def lower_node(p: P.Plan, catalog: P.Catalog, scans: Dict[int, Stream],
                            scans.get(index_stream_key(p)))
     if isinstance(p, P.Aggregate):
         child = lower_node(p.child, catalog, scans, params)
-        return _lower_aggregate(p, child, catalog, params)
+        with OX.kernel_scope("flare:agg"):
+            return _lower_aggregate(p, child, catalog, params)
     if isinstance(p, P.Sort):
         child = lower_node(p.child, catalog, scans, params)
-        return _lower_sort(p, child, catalog)
+        with OX.kernel_scope("flare:sort"):
+            return _lower_sort(p, child, catalog)
     if isinstance(p, P.Limit):
         child = lower_node(p.child, catalog, scans, params)
         n = min(p.n, child.n)
@@ -896,22 +915,25 @@ class Result:
         return int(self.mask.sum())
 
     def compact(self) -> Dict[str, np.ndarray]:
-        """Valid rows only, strings decoded, host dtypes per schema."""
-        if self.mask is None:
-            sel = slice(None)
-        else:
-            sel = np.flatnonzero(self.mask)
-        out = {}
-        for f in self.schema:
-            arr = np.asarray(self.cols[f.name])[sel]
-            d = self.dicts.get(f.name)
-            if d is not None:
-                lut = np.asarray(d, dtype=object)
-                out[f.name] = lut[arr]
-            elif f.dtype == T.STRING and arr.dtype == object:
-                out[f.name] = arr  # already-decoded strings (tuple engine)
+        """Valid rows only, strings decoded, host dtypes per schema.
+        The ``fetch`` span covers the copy of columns still on the
+        device to the host and their selection and decoding."""
+        with OT.span("fetch", cols=len(self.schema)):
+            if self.mask is None:
+                sel = slice(None)
             else:
-                out[f.name] = arr.astype(T.numpy_dtype(f.dtype))
+                sel = np.flatnonzero(self.mask)
+            out = {}
+            for f in self.schema:
+                arr = np.asarray(self.cols[f.name])[sel]
+                d = self.dicts.get(f.name)
+                if d is not None:
+                    lut = np.asarray(d, dtype=object)
+                    out[f.name] = lut[arr]
+                elif f.dtype == T.STRING and arr.dtype == object:
+                    out[f.name] = arr  # decoded strings (tuple engine)
+                else:
+                    out[f.name] = arr.astype(T.numpy_dtype(f.dtype))
         return out
 
     def scalar(self, name: Optional[str] = None):

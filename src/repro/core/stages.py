@@ -46,7 +46,6 @@ from repro.core import engines as ENG
 from repro.core import expr as E
 from repro.core import lower as L
 from repro.core import plan as P
-from repro.obs import export as OX
 from repro.obs import trace as OT
 from repro.persist import executable as PX
 from repro.persist import store as PSTORE
@@ -1183,26 +1182,27 @@ class Compiled:
 
     def _result_inner(self, **params: Any) -> L.Result:
         self._check_bindings(params)
-        if not OT.TRACER.on:  # hot path: zero tracing machinery
+        if not OT.active():  # hot path: zero tracing machinery
             t0 = time.perf_counter()
             out = self._exe(self._catalog, self._device_cache,
                             params or None)
             self.stats.run_s = time.perf_counter() - t0
             return out
-        mark = OT.TRACER.watermark()
+        buffered = OT.TRACER.on
+        mark = OT.TRACER.watermark() if buffered else 0
         with OT.span("execute", engine=self.engine_name,
-                     mode="sync") as sp, \
-                OX.device_annotation(f"flare:execute:{self.engine_name}"):
+                     mode="sync") as sp:
             t0 = time.perf_counter()
             out = self._exe(self._catalog, self._device_cache,
                             params or None)
             self.stats.run_s = time.perf_counter() - t0
-        sp.set(run_s=round(self.stats.run_s, 6))
-        try:
-            sp.set(rows=out.num_rows())
-        except Exception:
-            pass
-        self._last_trace = OT.Trace(OT.TRACER.since(mark))
+            sp.set(run_s=round(self.stats.run_s, 6))
+            try:
+                sp.set(rows=out.num_rows())
+            except Exception:
+                pass
+        if buffered:
+            self._last_trace = OT.Trace(OT.TRACER.since(mark))
         return out
 
     def submit(self, **params: Any) -> AsyncResult:

@@ -13,13 +13,12 @@ Span attributes become the event ``args`` (with ``span_id``/
 ``parent_id`` preserved so tooling -- ``tools/trace_ci_check.py`` --
 can rebuild the span tree from the JSON alone).
 
-Device-side naming: :func:`device_annotation` wraps host-side dispatch
-in ``jax.profiler.TraceAnnotation`` so query executions show up named
-in ``jax.profiler.trace`` device profiles, and :func:`kernel_scope`
-wraps native Pallas lowerings in ``jax.named_scope`` so the kernels
-themselves carry their pattern name ("flare:filter_scalar_agg") in the
-compiled program's op names / device profile.  Both degrade to no-ops
-if the profiler API is unavailable.
+Device-side naming: every span is itself a profiler annotation while a
+``jax.profiler`` session records (:mod:`repro.obs.trace`), and
+:func:`kernel_scope` wraps an operator's lowering in
+``jax.named_scope`` so the device operations it emits carry its name
+("flare:join.probe", "flare:filter_scalar_agg") in the compiled
+program's ``op_name`` metadata and the device profile's ``tf_op``.
 """
 from __future__ import annotations
 
@@ -105,20 +104,11 @@ def spans_from_chrome(doc: Dict[str, Any]) -> List[OT.Span]:
 # ---------------------------------------------------------------------------
 
 
-def device_annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` context manager (no-op fallback):
-    names host-side dispatch windows in jax device profiles."""
-    try:
-        import jax.profiler
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
-
-
 def kernel_scope(name: str):
     """``jax.named_scope`` context manager (no-op fallback): applied at
-    trace time around native Pallas lowerings so kernel ops carry their
-    pattern name into compiled programs and device profiles."""
+    trace time around an operator's lowering (a native Pallas kernel or
+    a generic operator's own work) so its ops carry the name into
+    compiled programs and device profiles."""
     try:
         import jax
         return jax.named_scope(name)

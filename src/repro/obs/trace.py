@@ -13,10 +13,18 @@ becomes its child), carry free-form attributes, and land in one
 process-wide buffer from which :mod:`repro.obs.export` renders
 Chrome-trace JSON and :func:`Trace.tree_str` renders EXPLAIN ANALYZE.
 
-Tracing is OFF by default and must cost nearly nothing when off: with
-``$FLARE_TRACE`` unset, :func:`span` is a single attribute check
-returning a shared no-op context manager -- no allocation, no clock
-read, no lock.  Enable with ``FLARE_TRACE=1`` (process-wide, read at
+While a ``jax.profiler`` session records, every span is also a
+``jax.profiler.TraceAnnotation`` named ``"flare:" + name`` that carries
+the span's attributes (those given later through :meth:`Span.set` too),
+so the program's spans land in the profiler's host plane on the same
+clock as the device's operations.  That holds whether or not the buffer
+is on.
+
+The buffer is OFF by default and a span must cost nearly nothing when
+the buffer is off and no profiler runs: :func:`span` is then one
+attribute check and one ``TraceAnnotation.is_enabled()`` call returning
+a shared no-op context manager -- no allocation, no clock read, no
+lock.  Enable the buffer with ``FLARE_TRACE=1`` (process-wide, read at
 import) or scoped via :func:`enable`/:func:`disable` or the
 :func:`capture` context manager (which also collects the spans recorded
 in its window -- the mechanism behind ``df.explain(analyze=True)`` and
@@ -30,33 +38,51 @@ import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 ENV_VAR = "FLARE_TRACE"
 #: Buffer cap: oldest spans are dropped past this (a long-lived traced
 #: server must not grow without bound).  Override via env.
 MAX_SPANS = int(os.environ.get("FLARE_TRACE_MAX_SPANS", "500000"))
 
 _OFF_VALUES = ("", "0", "false", "off", "no")
+#: Name prefix of a span's profiler annotation.
+PREFIX = "flare:"
 
 
 def _env_enabled() -> bool:
     return os.environ.get(ENV_VAR, "").strip().lower() not in _OFF_VALUES
 
 
+def _meta(attrs: Dict[str, Any]) -> Dict[str, Any]:
+    """Attributes as profiler metadata: numbers as they are, anything
+    else as text without the ``,``, ``#`` and ``=`` that delimit the
+    annotation's encoded metadata."""
+    out = {}
+    for k, v in attrs.items():
+        if not isinstance(v, (bool, int, float)):
+            v = str(v).replace(",", ";").replace("#", " ").replace("=", ":")
+        out[k] = v
+    return out
+
+
 class Span:
     """One timed phase: name, wall-clock window, attributes, tree links.
 
-    Context manager: ``__enter__`` stamps ``t0`` and pushes onto the
-    thread's span stack (so nested spans record this one as parent);
-    ``__exit__`` stamps ``t1``, pops, and appends to the tracer buffer.
-    ``set(**attrs)`` attaches provenance (cache hits, dispatch reasons,
-    row counts) to the open span.
+    Context manager: ``__enter__`` stamps ``t0``, pushes onto the
+    thread's span stack (so nested spans record this one as parent) and
+    opens the profiler annotation if a profiler session records;
+    ``__exit__`` stamps ``t1``, closes the annotation, pops, and appends
+    to the tracer buffer when ``record``.  ``set(**attrs)`` attaches
+    provenance (cache hits, dispatch reasons, row counts) to the open
+    span and its annotation.
     """
 
     __slots__ = ("name", "span_id", "parent_id", "tid", "t0", "t1",
-                 "attrs")
+                 "attrs", "record", "_note")
 
     def __init__(self, name: str, span_id: int, parent_id: Optional[int],
-                 tid: int, attrs: Dict[str, Any]):
+                 tid: int, attrs: Dict[str, Any], record: bool = True):
         self.name = name
         self.span_id = span_id
         self.parent_id = parent_id
@@ -64,9 +90,13 @@ class Span:
         self.t0 = 0.0
         self.t1 = 0.0
         self.attrs = attrs
+        self.record = record
+        self._note: Optional[TraceAnnotation] = None
 
     def set(self, **attrs: Any) -> "Span":
         self.attrs.update(attrs)
+        if self._note is not None:
+            self._note.set_metadata(**_meta(attrs))
         return self
 
     @property
@@ -85,6 +115,10 @@ class Span:
         stack = TRACER._stack()
         self.parent_id = stack[-1].span_id if stack else None
         stack.append(self)
+        if TraceAnnotation.is_enabled():
+            self._note = TraceAnnotation(PREFIX + self.name,
+                                         **_meta(self.attrs))
+            self._note.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -92,12 +126,18 @@ class Span:
         self.t1 = time.perf_counter()
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
+        if self._note is not None:
+            if exc_type is not None:
+                self._note.set_metadata(error=exc_type.__name__)
+            self._note.__exit__(None, None, None)
+            self._note = None
         stack = TRACER._stack()
         if stack and stack[-1] is self:
             stack.pop()
         elif self in stack:  # tolerate out-of-order exits
             stack.remove(self)
-        TRACER._record(self)
+        if self.record:
+            TRACER._record(self)
         return False
 
     def __repr__(self):
@@ -224,17 +264,22 @@ TRACER = Tracer()
 
 
 def span(name: str, **attrs: Any):
-    """Open a span (context manager).  Near-free when tracing is off."""
-    if not TRACER.on:
-        return NULL_SPAN
-    return TRACER.start(name, attrs)
+    """Open a span (context manager): recorded in the buffer when it is
+    on, an annotation while a profiler session records, and near-free
+    when neither."""
+    if TRACER.on:
+        return TRACER.start(name, attrs)
+    if TraceAnnotation.is_enabled():
+        return Span(name, 0, None, threading.get_ident(), attrs,
+                    record=False)
+    return NULL_SPAN
 
 
 def current_span():
     """The innermost open span of this thread (NULL_SPAN when none or
     disabled) -- lets helpers attach provenance to their caller's span
     without threading the object through."""
-    if not TRACER.on:
+    if not active():
         return NULL_SPAN
     stack = TRACER._stack()
     return stack[-1] if stack else NULL_SPAN
@@ -242,6 +287,12 @@ def current_span():
 
 def enabled() -> bool:
     return TRACER.on
+
+
+def active() -> bool:
+    """True when a span would do anything: the buffer is on or a
+    profiler session records."""
+    return TRACER.on or TraceAnnotation.is_enabled()
 
 
 def enable() -> None:

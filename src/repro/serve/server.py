@@ -22,6 +22,7 @@ that want fire-and-forget submission.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
@@ -80,7 +81,12 @@ class ServeFuture:
     """
 
     def __init__(self, stats: ServeStats, submit_t: float,
-                 deadline_t: Optional[float] = None):
+                 deadline_t: Optional[float] = None, req: int = 0):
+        #: the request's sequence number at admission, and the number of
+        #: the dispatch that ran it: the attributes that join a
+        #: request's spans into one chain
+        self.req = req
+        self.batch: Optional[int] = None
         self._dispatched = threading.Event()
         self._handle: Optional[S.AsyncResult] = None
         self._error: Optional[BaseException] = None
@@ -92,8 +98,10 @@ class ServeFuture:
         self._latency_recorded = False
         self._lock = threading.Lock()
 
-    def _assign(self, handle: S.AsyncResult) -> None:
+    def _assign(self, handle: S.AsyncResult,
+                batch: Optional[int] = None) -> None:
         self._handle = handle
+        self.batch = batch
         self._dispatched.set()
 
     def _fail(self, err: BaseException) -> None:
@@ -125,11 +133,15 @@ class ServeFuture:
         if self._error is not None:
             raise self._error
         t_sync = time.perf_counter()
-        with OT.span("serve.sync"):
-            if deadline is None:
+        with OT.span("serve.sync", req=self.req, batch=self.batch):
+            with OT.span("serve.wait", req=self.req) as sp:
+                if deadline is None:
+                    self._handle.block_until_ready()
+                else:
+                    polls, sleep_s = self._wait_before(deadline)
+                    sp.set(polls=polls, last_sleep_ms=sleep_s * 1e3)
+            with OT.span("serve.finalize", req=self.req):
                 out = self._handle.result()
-            else:
-                out = self._sync_before(deadline)
         with self._lock:
             if not self._latency_recorded:
                 self._latency_recorded = True
@@ -138,11 +150,14 @@ class ServeFuture:
                 self._stats.record_sync(now - t_sync)
         return out
 
-    def _sync_before(self, deadline: float) -> Any:
-        """Materialise within the remaining budget: poll the handle's
-        readiness probe (cheap, non-blocking) and only pay the blocking
-        sync once the device value exists."""
+    def _wait_before(self, deadline: float) -> Tuple[int, float]:
+        """Wait within the remaining budget for the device value: poll
+        the handle's readiness probe (cheap, non-blocking) with sleeps
+        that double from 0.5 ms to 10 ms, so the blocking transfer is
+        only paid once the value exists.  Returns the number of sleeps
+        and the last one's length in seconds."""
         step = 0.0005
+        polls, slept = 0, 0.0
         while not self._handle.ready():
             remaining = deadline - time.perf_counter()
             if remaining <= 0:
@@ -150,9 +165,11 @@ class ServeFuture:
                     "request dispatched but device sync did not "
                     "complete in time; the batch is still in flight -- "
                     "read the future again with a longer timeout")
-            time.sleep(min(step, remaining))
+            slept = min(step, remaining)
+            time.sleep(slept)
+            polls += 1
             step = min(step * 2, 0.01)
-        return self._handle.result()
+        return polls, slept
 
     def compact(self, timeout: Optional[float] = None) -> Dict[str, Any]:
         return self.result(timeout).compact()
@@ -222,6 +239,9 @@ class QueryServer:
         self._lock = threading.Lock()
         self._worker: Optional[threading.Thread] = None
         self._stop = threading.Event()
+        # sequence numbers of admitted requests and of dispatches
+        self._reqs = itertools.count()
+        self._batches = itertools.count()
         OM.REGISTRY.register("serve", self)
         if warm_start:
             self.preload()
@@ -300,9 +320,10 @@ class QueryServer:
                deadline_s: Optional[float]) -> ServeFuture:
         now = time.perf_counter()
         fut = ServeFuture(self.stats, now,
-                          None if deadline_s is None else now + deadline_s)
+                          None if deadline_s is None else now + deadline_s,
+                          next(self._reqs))
         req = _Request(name, params, fut)
-        with OT.span("serve.submit", template=name) as sp:
+        with OT.span("serve.submit", template=name, req=fut.req) as sp:
             with self._lock:
                 if (self.max_queue is not None
                         and len(self._queue) >= self.max_queue):
@@ -378,9 +399,11 @@ class QueryServer:
         future.  log2(batch) extra dispatches in the worst case, zero
         on the happy path.
         """
+        batch = next(self._batches)
         try:
-            with OT.span("serve.dispatch", template=name,
-                         requests=len(reqs)) as sp:
+            with OT.span("serve.dispatch", template=name, batch=batch,
+                         requests=len(reqs), req_first=reqs[0].future.req,
+                         req_last=reqs[-1].future.req) as sp:
                 FZ.fault_point("serve.dispatch", template=name)
                 compiled = self.compiled_for(name)
                 c0 = compiled.stats.compile_s
@@ -391,8 +414,7 @@ class QueryServer:
                 sp.set(bucket=bucket,
                        occupancy=round(len(reqs) / max(1, bucket), 4))
             self.stats.record_batch(len(reqs), bucket,
-                                    compiled.stats.compile_s - c0,
-                                    compiled.stats.run_s)
+                                    compiled.stats.compile_s - c0)
         except BaseException as err:
             if len(reqs) == 1:  # isolated: fail ONLY this waiter
                 self.stats.poisoned += 1
@@ -409,7 +431,7 @@ class QueryServer:
             self._dispatch_isolating(name, reqs[mid:])
             return
         for r, h in zip(reqs, handles):
-            r.future._assign(h)
+            r.future._assign(h, batch)
 
     def serve(self, requests: Iterable[Tuple[str, Dict[str, Any]]],
               block: bool = True) -> List[Any]:

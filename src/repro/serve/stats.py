@@ -4,7 +4,8 @@ Flare's deployment mode (paper section 5) lives or dies on amortisation:
 compile once, batch many.  :class:`ServeStats` measures exactly that --
 how full the coalesced batches ran (occupancy), how many device
 dispatches the queue saved (coalesce ratio), what the requests actually
-observed (p50/p99 latency), and where the time went (compile vs run).
+observed (p50/p99 latency), and where the time went (compile, queue
+wait, sync).
 DESIGN.md section 11 describes how the server produces these.
 """
 from __future__ import annotations
@@ -56,7 +57,6 @@ class ServeStats:
     occupancy_sum: float = 0.0
     max_queue_depth: int = 0
     compile_s: float = 0.0
-    run_s: float = 0.0
     latencies_s: List[float] = dataclasses.field(default_factory=list)
     #: Per-request admission-queue wait: submit -> batch dispatch.
     queue_s: List[float] = dataclasses.field(default_factory=list)
@@ -71,11 +71,10 @@ class ServeStats:
     poisoned: int = 0
 
     def record_batch(self, size: int, bucket: int,
-                     compile_s: float, run_s: float) -> None:
+                     compile_s: float) -> None:
         self.batches += 1
         self.occupancy_sum += size / max(1, bucket)
         self.compile_s += compile_s
-        self.run_s += run_s
 
     def record_latency(self, seconds: float) -> None:
         self.completed += 1
@@ -128,7 +127,6 @@ class ServeStats:
             "batch_occupancy": round(self.batch_occupancy(), 4),
             "max_queue_depth": self.max_queue_depth,
             "compile_s": round(self.compile_s, 6),
-            "run_s": round(self.run_s, 6),
             "p50_ms": round(self.p50_s() * 1e3, 3),
             "p95_ms": round(self.p95_s() * 1e3, 3),
             "p99_ms": round(self.p99_s() * 1e3, 3),

@@ -7,9 +7,12 @@ plus the serve/store/index names) is the contract flare_top,
 trace_ci_check and the EXPLAIN ANALYZE renderer all consume -- renaming
 a span is an interface change and must update all of them.
 """
+import glob
 import json
+import os
 import sys
 
+import jax
 import pytest
 
 import conftest
@@ -52,6 +55,52 @@ def test_disabled_mode_is_a_noop(monkeypatch):
         inner.set(more="attrs")  # all no-ops
     assert len(OT.TRACER.spans()) == before
     assert not OT.enabled()
+
+
+def _profiled_spans(trace_dir):
+    """``(name, start_ns, end_ns, stats)`` of every ``flare:`` host event
+    in the newest profile under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    path = max(glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                      "*", "*.xplane.pb")),
+               key=os.path.getmtime)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(OT.PREFIX):
+                    out.append((e.name[len(OT.PREFIX):], e.start_ns,
+                                e.end_ns, dict(e.stats)))
+    return out
+
+
+def test_span_is_a_profiler_annotation(tmp_path, monkeypatch):
+    """Under a profiler session a span is a ``flare:`` host event that
+    carries its attributes, those set later included, with the buffer
+    off."""
+    monkeypatch.delenv(OT.ENV_VAR, raising=False)
+    OT.TRACER.refresh_from_env()
+    before = len(OT.TRACER.spans())
+    with jax.profiler.trace(str(tmp_path)):
+        assert OT.active()
+        with OT.span("demo", req=7, note="a,b=c#d") as sp:
+            assert sp is not OT.NULL_SPAN
+            sp.set(polls=3)
+    assert len(OT.TRACER.spans()) == before  # the buffer stayed off
+    got = [st for name, _, _, st in _profiled_spans(tmp_path)
+           if name == "demo"]
+    assert got == [{"req": 7, "note": "a;b:c d", "polls": 3}]
+
+
+def test_span_is_null_with_no_profiler_and_buffer_off(tmp_path,
+                                                      monkeypatch):
+    monkeypatch.delenv(OT.ENV_VAR, raising=False)
+    OT.TRACER.refresh_from_env()
+    with jax.profiler.trace(str(tmp_path)):
+        pass
+    assert not OT.active()
+    assert OT.span("after", key="value") is OT.NULL_SPAN
+    assert OT.current_span() is OT.NULL_SPAN
 
 
 def test_span_nesting_parent_ids_and_attrs():
@@ -150,6 +199,60 @@ def test_served_path_span_coverage(ctx):
     assert "execute" in trace.descendant_names(dispatch)
     batch_exec = trace.first("execute")
     assert batch_exec.attrs["mode"] == "batch"
+    # each request's sync splits into waiting for the device and
+    # bringing its rows to the host
+    for f in futs:
+        sync = next(s for s in trace.find("serve.sync")
+                    if s.attrs["req"] == f.req)
+        assert sync.attrs["batch"] == dispatch.attrs["batch"]
+        assert [c.name for c in trace.children(sync)] == [
+            "serve.wait", "serve.finalize"]
+
+
+def test_served_request_chain_in_profile(ctx, tmp_path, monkeypatch):
+    """One served request leaves submit -> dispatch -> sync {wait,
+    finalize} in the profile, joined by its sequence number."""
+    from repro.serve import QueryServer
+    monkeypatch.delenv(OT.ENV_VAR, raising=False)
+    OT.TRACER.refresh_from_env()
+    server = QueryServer(ctx)
+    b = Q.TEMPLATE_BINDINGS["q6"][0]
+    server.serve([("q6", b)])  # compiles outside the profile
+    with jax.profiler.trace(str(tmp_path)):
+        fut = server.submit("q6", **b)
+        server.flush()
+        fut.result(timeout=30)
+    spans = _profiled_spans(tmp_path)
+
+    def one(name, match):
+        got = [(s, e, st) for n, s, e, st in spans
+               if n == name and match(st)]
+        assert len(got) == 1, (name, spans)
+        return got[0]
+    req = fut.req
+    submit = one("serve.submit", lambda st: st["req"] == req)
+    dispatch = one("serve.dispatch",
+                   lambda st: st["req_first"] <= req <= st["req_last"])
+    sync = one("serve.sync", lambda st: st["req"] == req)
+    wait = one("serve.wait", lambda st: st["req"] == req)
+    final = one("serve.finalize", lambda st: st["req"] == req)
+    assert sync[2]["batch"] == dispatch[2]["batch"] == fut.batch
+    assert dispatch[2]["requests"] == 1 and dispatch[2]["bucket"] == 1
+    assert submit[1] <= dispatch[0] and dispatch[1] <= sync[0]
+    assert sync[0] <= wait[0] <= wait[1] <= final[0] <= final[1] <= sync[1]
+    assert wait[2]["polls"] >= 0
+
+
+def test_generic_lowering_names_operators_on_device(ctx):
+    """The compiled q3 carries each generic operator's scope in its
+    ops' ``op_name`` metadata, which device profiles keep as
+    ``tf_op``."""
+    compiled = Q.q3(ctx).lower(engine="compiled").compile(
+        cache=CompileCache())
+    hlo = compiled._exe.jax_exe.as_text()
+    for scope in ("flare:join.probe", "flare:join.gather", "flare:agg",
+                  "flare:filter", "flare:sort"):
+        assert f"/{scope}/" in hlo, scope
 
 
 def test_last_trace_rides_on_compiled(ctx):
