@@ -31,6 +31,7 @@ section 4.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -128,11 +129,48 @@ class JoinIndex:
     per (table, key columns) at preload/first use and closed over by
     every compiled program that probes this build side; the in-program
     ``argsort`` the join would otherwise re-run per execution is gone.
+
+    ``slots`` holds the slot tables of direct joins, one per combined
+    key domain size (:meth:`slot_table`): derived from ``perm``/``keys``
+    on first use, never persisted, and dropped with the index.
     """
 
     perm: jnp.ndarray     # int32 [n]: stable argsort of the combined keys
     keys: jnp.ndarray     # int32 [n]: combined keys, sorted
     unique: bool          # verified at build: no duplicate combined keys
+    slots: Dict[int, jnp.ndarray] = dataclasses.field(
+        default_factory=dict, repr=False)
+
+    def slot_table(self, domain: int) -> jnp.ndarray:
+        """int32 ``[domain]``: ``slots[k]`` is the build row the sorted
+        probe finds for combined key ``k`` (the first in stable sort
+        order, so the lowest row index among duplicates), -1 where no
+        row holds ``k``.  Built on the device on first use per domain
+        size; a key outside ``[0, domain)`` contradicts the declared
+        domain and raises."""
+        table = self.slots.get(domain)
+        if table is None:
+            with OT.span("slot_build", rows=int(self.keys.shape[0]),
+                         domain=domain):
+                lo, hi = (int(v) for v in
+                          jax.device_get((self.keys[0], self.keys[-1])))
+                if lo < 0 or hi >= domain:
+                    raise ValueError(
+                        f"join keys span [{lo}, {hi}], outside their "
+                        f"declared domain [0, {domain}) (Field.domain)")
+                table = _slot_table(self.perm, self.keys, domain)
+            self.slots[domain] = table
+        return table
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _slot_table(perm: jnp.ndarray, keys: jnp.ndarray,
+                domain: int) -> jnp.ndarray:
+    # scatter-min: duplicates resolve to the lowest row, whatever order
+    # the scatter applies them in (perm < n < INT32_MAX)
+    none = jnp.iinfo(jnp.int32).max
+    first = jnp.full((domain,), none, jnp.int32).at[keys].min(perm)
+    return jnp.where(first == none, jnp.int32(-1), first)
 
 
 class IndexCache:
@@ -301,8 +339,8 @@ class DeviceCache:
     The paper's experiments distinguish "direct CSV" from "preloaded"
     execution; with a warm cache our engines run purely in-memory.
     ``indexes`` is the companion :class:`IndexCache` holding build-side
-    join indexes (sorted permutation + sorted keys) with the same
-    lifetime as the cached columns.
+    join indexes (sorted permutation + sorted keys, and the slot tables
+    of direct joins) with the same lifetime as the cached columns.
     """
 
     kind = "device"

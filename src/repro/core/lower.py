@@ -8,8 +8,11 @@ compiles with GCC, we trace into a jaxpr and compile with XLA.
 TPU adaptation (DESIGN.md section 3)::
 
     Filter      -> boolean selection mask (predication, never compacts)
-    Hash join   -> sorted-array join: argsort build keys once, probe with
-                   vectorised searchsorted + gather (N:1 / PK-FK joins)
+    Hash join   -> N:1 / PK-FK join against a build-side index made once:
+                   a dense key domain (at most 8 slots per build row)
+                   probes a slot table of row positions with one gather;
+                   sparse or undeclared keys binary-search the sorted
+                   keys (searchsorted + gather)
     Hash agg    -> segment-sum onto the dense, statically-bounded group
                    domain derived from dictionaries / key domains
     Strings     -> int32 dictionary codes; string predicates evaluated on
@@ -24,6 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import fnmatch
+import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -35,10 +39,16 @@ from repro.core import expr as E
 from repro.core import ml as ML
 from repro.core import plan as P
 from repro.obs import export as OX
+from repro.obs import metrics as OM
 from repro.obs import trace as OT
 from repro.relational import table as T
 
 _I32_MAX = np.int32(2 ** 31 - 1)
+
+#: A cached join probes a slot table over its combined key domain when
+#: the domain holds at most this many slots per build row (o_orderkey's
+#: sparse keys take 4); a sparser domain keeps the binary search.
+_DIRECT_SLOTS_PER_ROW = 8
 
 # ---------------------------------------------------------------------------
 # static (phase A) column info
@@ -372,13 +382,33 @@ class JoinIndexSpec:
     filtered build side: the cached index covers the UNFILTERED table
     and the probe validates the matched row's filter mask post-probe --
     exact because the keys are unique (declared via ``Field.unique``,
-    verified at index build time).
+    verified at index build time).  ``direct`` marks a dense combined
+    key domain (every key declared, at most ``_DIRECT_SLOTS_PER_ROW``
+    slots per build row): the program also takes the index's slot
+    table and probes it with one gather instead of a binary search.
     """
 
     table: str
     key_cols: Tuple[str, ...]
     doms: Tuple[int, ...]
     masked: bool
+    direct: bool
+
+    @property
+    def domain(self) -> int:
+        """Size of the combined key domain: the slot table's length."""
+        return math.prod(self.doms)
+
+    @property
+    def n_arrays(self) -> int:
+        """How many index arrays the program takes for this join."""
+        return 3 if self.direct else 2
+
+    def arg_shapes(self, build_rows: int) -> Tuple[Tuple[int, ...], ...]:
+        """Shapes of the int32 arrays the program takes for this join:
+        ``perm`` and ``keys``, then the slot table when direct."""
+        shapes = ((build_rows,), (build_rows,))
+        return shapes + ((self.domain,),) if self.direct else shapes
 
 
 def resolve_build_index(p: P.Join, catalog: P.Catalog
@@ -425,7 +455,11 @@ def resolve_build_index(p: P.Join, catalog: P.Catalog
         return None, ("filtered build side without a declared-unique key "
                       "(Field.unique): post-probe mask validation would "
                       "be inexact under duplicate keys")
-    return JoinIndexSpec(node.table, key_cols, doms, masked), "ok"
+    # 'sortmerge' sorts the probe side on purpose (paper Fig. 6)
+    direct = ((p.strategy or "sorted") != "sortmerge"
+              and all(d < int(_I32_MAX) for d in doms)
+              and math.prod(doms) <= _DIRECT_SLOTS_PER_ROW * tbl.num_rows)
+    return JoinIndexSpec(node.table, key_cols, doms, masked, direct), "ok"
 
 
 def join_index_plan(p: P.Plan, catalog: P.Catalog
@@ -475,14 +509,17 @@ def _join_info(p: P.Join, left: StaticInfo, right: StaticInfo
     return StaticInfo(cols, left.n_rows)
 
 
+#: A join's cached index stream: (perm, sorted keys, slot table or None).
+JoinIndexStream = Tuple[jnp.ndarray, jnp.ndarray, Optional[jnp.ndarray]]
+
+
 def _lower_join(p: P.Join, left: Stream, right: Stream,
                 catalog: P.Catalog,
-                jindex: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None
-                ) -> Stream:
+                jindex: Optional[JoinIndexStream] = None) -> Stream:
     """The join's own work under two device names: ``flare:join.probe``
-    (combined keys, the build side's sort when no index is cached,
-    ``searchsorted``, clip, match and validity masks) and
-    ``flare:join.gather`` (the build-side column gathers)."""
+    (combined keys, the build side's sort when no index is cached, the
+    slot-table gather or ``searchsorted``, clip, match and validity
+    masks) and ``flare:join.gather`` (the build-side column gathers)."""
     with OX.kernel_scope("flare:join.probe"):
         pos, matched, pmask = _join_probe(p, left, right, jindex)
         if p.how == "semi":
@@ -507,7 +544,7 @@ def _lower_join(p: P.Join, left: Stream, right: Stream,
 
 
 def _join_probe(p: P.Join, left: Stream, right: Stream,
-                jindex: Optional[Tuple[jnp.ndarray, jnp.ndarray]]
+                jindex: Optional[JoinIndexStream]
                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """(build-side row of each probe row's tentative match, the match
     mask, the probe side's mask)."""
@@ -521,16 +558,19 @@ def _join_probe(p: P.Join, left: Stream, right: Stream,
             if d >= int(_I32_MAX):
                 raise TypeError("composite join keys need Field.domain bounds")
     kp = _combine_keys([left.cols[k] for k in p.left_on], doms)
+    pmask = left.the_mask()
 
     # --- build side: the 'hash table' analogue --------------------------------
+    slots = None
     if jindex is not None:
         # cached index (DESIGN.md section 10): the sorted permutation +
-        # sorted keys were built ONCE at preload/first use and enter the
-        # program as arguments -- no in-program argsort.  The index
-        # covers the unfiltered base table; a filtered build side is
-        # validated post-probe against the matched row's mask (exact:
-        # keys are unique, see resolve_build_index).
-        perm, kb_sorted = jindex
+        # sorted keys (and, over a dense key domain, the slot table)
+        # were built ONCE at preload/first use and enter the program as
+        # arguments -- no in-program argsort.  The index covers the
+        # unfiltered base table; a filtered build side is validated
+        # post-probe against the matched row's mask (exact: keys are
+        # unique, see resolve_build_index).
+        perm, kb_sorted, slots = jindex
         validate_mask = right.mask
     else:
         kb = _combine_keys([right.cols[k] for k in p.right_on], doms)
@@ -540,21 +580,30 @@ def _join_probe(p: P.Join, left: Stream, right: Stream,
         kb_sorted = kb[perm]
         validate_mask = None
 
-    pmask = left.the_mask()
-    if strategy == "sortmerge":
-        # Paper Fig. 6: sort-merge also sorts the (large) probe side, then
-        # un-permutes results -- strictly more work, kept for comparison.
-        probe_perm = jnp.argsort(kp)
-        kp_s = kp[probe_perm]
-        idx_s = jnp.searchsorted(kb_sorted, kp_s)
-        inv = jnp.argsort(probe_perm)
-        idx = idx_s[inv]
+    if slots is not None:
+        # slot table: slots[k] is the first build row (in stable sort
+        # order) holding key k, -1 where none -- one gather a probe row
+        OM.REGISTRY.inc("join.probe.direct")
+        d = slots.shape[0]
+        pos = slots[jnp.clip(kp, 0, d - 1)]
+        matched = (kp >= 0) & (kp < d) & (pos >= 0) & pmask
+        pos = jnp.maximum(pos, 0)
     else:
-        idx = jnp.searchsorted(kb_sorted, kp)
-
-    idx_c = jnp.clip(idx, 0, kb_sorted.shape[0] - 1)
-    pos = perm[idx_c]  # build-table row of each (tentative) match
-    matched = (kb_sorted[idx_c] == kp) & pmask
+        OM.REGISTRY.inc("join.probe.sorted")
+        if strategy == "sortmerge":
+            # Paper Fig. 6: sort-merge also sorts the (large) probe side,
+            # then un-permutes results -- strictly more work, kept for
+            # comparison.
+            probe_perm = jnp.argsort(kp)
+            kp_s = kp[probe_perm]
+            idx_s = jnp.searchsorted(kb_sorted, kp_s)
+            inv = jnp.argsort(probe_perm)
+            idx = idx_s[inv]
+        else:
+            idx = jnp.searchsorted(kb_sorted, kp)
+        idx_c = jnp.clip(idx, 0, kb_sorted.shape[0] - 1)
+        pos = perm[idx_c]  # build-table row of each (tentative) match
+        matched = (kb_sorted[idx_c] == kp) & pmask
     if validate_mask is not None:
         matched = matched & validate_mask[pos]
     return pos, matched, pmask
@@ -981,9 +1030,10 @@ def build_callable(p: P.Plan, catalog: P.Catalog,
     ``index_layout`` lists the :class:`JoinIndexSpec` of every join
     whose build side is served by the cached base-table index (DESIGN.md
     section 10): between the scan columns and the params, ``fn`` takes
-    one (perm, sorted-keys) int32 array pair per entry, in layout order.
-    Engines fetch those from :class:`repro.core.engines.IndexCache` at
-    call time, so the "hash table" is built at load time and the
+    the int32 arrays of :meth:`JoinIndexSpec.arg_shapes` per entry, in
+    layout order: (perm, sorted keys), plus the slot table for a direct
+    spec.  Engines fetch those from :class:`repro.core.engines.IndexCache`
+    at call time, so the "hash table" is built at load time and the
     program only probes.  Setting ``p._join_index_disabled`` (the
     ``lower(join_index=False)`` escape hatch) keeps every join on its
     in-program argsort.
@@ -1037,10 +1087,10 @@ def build_callable(p: P.Plan, catalog: P.Catalog,
                 scans[id(s)] = scan_stream_fn(s, cols, static)
             else:
                 scans[id(s)] = Stream(cols, None, static)
-        for jid, _spec in index_items:
-            perm = next(it)
-            keys = next(it)
-            scans[("joinidx", jid)] = (perm, keys)
+        for jid, spec in index_items:
+            perm, keys = next(it), next(it)
+            slots = next(it) if spec.direct else None
+            scans[("joinidx", jid)] = (perm, keys, slots)
         env = {spec.name: next(it) for spec in param_specs}
         if ml_root:
             stream = lower_node(p.child, catalog, scans, env or None)
@@ -1089,6 +1139,6 @@ def build_batch_callable(p: P.Plan, catalog: P.Catalog,
     fn, layout, index_layout, out_info = build_callable(p, catalog,
                                                         param_specs)
     n_shared = (sum(len(names) for _, names in layout)
-                + 2 * len(index_layout))
+                + sum(s.n_arrays for s in index_layout))
     in_axes = (None,) * n_shared + (0,) * len(param_specs)
     return jax.vmap(fn, in_axes=in_axes), layout, index_layout, out_info

@@ -513,9 +513,9 @@ class ParallelEngine:
         for spec in index_layout:
             # replicated like the build tables they index (the spine is
             # always the probe side, never a build side)
-            n = catalog.table(spec.table).num_rows
-            for _ in range(2):  # perm, keys
-                avals.append(jax.ShapeDtypeStruct((n,), jnp.int32))
+            for shape in spec.arg_shapes(
+                    catalog.table(spec.table).num_rows):
+                avals.append(jax.ShapeDtypeStruct(shape, jnp.int32))
                 in_specs.append(P())
         for s in param_specs:
             avals.append(jax.ShapeDtypeStruct(

@@ -158,10 +158,12 @@ class Join(Plan):
     probe (``left``) side -- i.e. right keys are unique (PK--FK join).
 
     TPU adaptation (DESIGN.md section 3): lowered to a *sorted-array join*
-    (sort build keys once, vectorised ``searchsorted`` probe + gather)
-    instead of a pointer-chasing hash table.  ``how`` in {inner, left,
-    semi, anti}.  ``strategy`` in {sorted, sortmerge} is picked by the
-    optimizer (paper Fig. 6 compares strategies).
+    (sort build keys once, vectorised ``searchsorted`` probe + gather;
+    a cached build side over a dense declared key domain probes a slot
+    table with one gather instead) rather than a pointer-chasing hash
+    table.  ``how`` in {inner, left, semi, anti}.  ``strategy`` in
+    {sorted, sortmerge} is picked by the optimizer (paper Fig. 6
+    compares strategies).
     """
 
     left: Plan

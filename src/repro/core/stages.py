@@ -102,7 +102,7 @@ def template_key(engine: str, p: P.Plan, catalog: P.Catalog,
         if index_specs is None:  # direct callers; lower_plan passes its own
             index_specs, _ = L.join_index_plan(p, catalog)
         parts.append(("joinidx", tuple(
-            (s.table, s.key_cols, s.doms, s.masked)
+            (s.table, s.key_cols, s.doms, s.masked, s.direct)
             for s in index_specs.values())))
     return tuple(parts)
 
@@ -250,7 +250,7 @@ def _load_persisted_exec(store: "PSTORE.ArtifactStore", digest: str,
     pdtypes = [jax.dtypes.canonicalize_dtype(T.numpy_dtype(s.dtype))
                for s in param_specs]
     n_args = (sum(len(names) for _, names in layout)
-              + 2 * len(index_layout) + len(param_specs))
+              + sum(s.n_arrays for s in index_layout) + len(param_specs))
     if is_value:
         schema = out_info = None
         try:
@@ -510,8 +510,9 @@ class _WholeQueryArtifact:
     fn: Callable
     # (table_name, column_names) per scan, in argument order
     layout: Tuple[Tuple[str, Tuple[str, ...]], ...]
-    # cached build-side join indexes: one (perm, keys) argument pair per
-    # spec, between the scan columns and the params (DESIGN.md sec. 10)
+    # cached build-side join indexes: the arrays of each spec's
+    # arg_shapes, between the scan columns and the params (DESIGN.md
+    # sec. 10)
     index_layout: Tuple[L.JoinIndexSpec, ...]
     avals: Tuple[jax.ShapeDtypeStruct, ...]
     param_specs: Tuple[E.Param, ...]
@@ -525,15 +526,18 @@ class _WholeQueryArtifact:
 def index_args(index_layout: Tuple[L.JoinIndexSpec, ...],
                catalog: P.Catalog, device_cache: ENG.DeviceCache
                ) -> List[jnp.ndarray]:
-    """Fetch the (perm, sorted-keys) pairs for an executable's join-index
-    layout from the device cache (built on first use, then device-
-    resident -- the IndexCache hit-rate telemetry counts this)."""
+    """Fetch the index arrays for an executable's join-index layout from
+    the device cache: (perm, sorted keys) per join, plus the slot table
+    of a direct join (built on first use, then device-resident -- the
+    IndexCache hit-rate telemetry counts this)."""
     args: List[jnp.ndarray] = []
     for spec in index_layout:
         idx = device_cache.get_index(catalog.table(spec.table),
                                      spec.key_cols, spec.doms)
         args.append(idx.perm)
         args.append(idx.keys)
+        if spec.direct:
+            args.append(idx.slot_table(spec.domain))
     return args
 
 
@@ -541,7 +545,7 @@ def shared_avals(layout: Tuple[Tuple[str, Tuple[str, ...]], ...],
                  index_layout: Sequence[L.JoinIndexSpec],
                  catalog: P.Catalog) -> List[jax.ShapeDtypeStruct]:
     """Avals of a template's binding-independent arguments: the scan
-    columns then the join-index (perm, keys) pairs.  Shared between the
+    columns then the join-index arrays.  Shared between the
     single-binding and the vmap-batched lowering -- the batched program
     broadcasts exactly these and stacks only the params."""
     avals: List[jax.ShapeDtypeStruct] = []
@@ -552,9 +556,8 @@ def shared_avals(layout: Tuple[Tuple[str, Tuple[str, ...]], ...],
                 (tbl.num_rows,),
                 jax.dtypes.canonicalize_dtype(tbl[n].dtype)))
     for spec in index_layout:
-        n = catalog.table(spec.table).num_rows
-        avals.append(jax.ShapeDtypeStruct((n,), jnp.int32))  # perm
-        avals.append(jax.ShapeDtypeStruct((n,), jnp.int32))  # keys
+        for shape in spec.arg_shapes(catalog.table(spec.table).num_rows):
+            avals.append(jax.ShapeDtypeStruct(shape, jnp.int32))
     return avals
 
 
@@ -564,7 +567,7 @@ def _marshal_args(layout: Tuple[Tuple[str, Tuple[str, ...]], ...],
                   ) -> List[jnp.ndarray]:
     """The binding-independent argument prefix of a whole-query
     executable: device-resident scan columns (in layout order) followed
-    by the join-index (perm, keys) pairs.  Shared by freshly-compiled
+    by the join-index arrays.  Shared by freshly-compiled
     and store-loaded executors -- the layout is a pure function of
     (plan, catalog), which is what lets a deserialized executable be
     re-bound to arguments without ever tracing."""
@@ -1428,7 +1431,8 @@ def _add_index_decisions(p: P.Plan, catalog: P.Catalog,
     for join, spec, reason in decisions:
         report.index_decisions.append(NR.Decision(
             pattern="join-index", node=join.describe(),
-            fired=spec is not None, mode="cached" if spec else "",
+            fired=spec is not None,
+            mode=("direct" if spec.direct else "sorted") if spec else "",
             reason="ok" if spec else reason))
     return report
 

@@ -943,7 +943,7 @@ def _emit_join_probe(frag: R.Fragment, catalog: P.Catalog):
             raise RuntimeError(
                 "join-probe fragment lowered without its cached index "
                 "stream; the engine must run lower.build_callable")
-        perm, keys = jidx
+        perm, keys, _ = jidx  # the kernel searches the sorted keys
 
         def _param(name):
             if params is None or name not in params:

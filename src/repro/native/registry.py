@@ -189,9 +189,10 @@ class DispatchReport:
 
     ``index_decisions`` is the join-index section (DESIGN.md sec. 10):
     one entry per join, saying whether its build side probes the cached
-    base-table index (``fired``) or rebuilds the sorted keys in-program,
-    and why -- recorded for ANY compiled/parallel template with joins,
-    native or not.
+    base-table index (``fired``; ``mode`` "direct" for the slot-table
+    probe of a dense key domain, "sorted" for the binary search) or
+    rebuilds the sorted keys in-program, and why -- recorded for ANY
+    compiled/parallel template with joins, native or not.
     """
 
     decisions: List[Decision] = dataclasses.field(default_factory=list)
@@ -243,7 +244,8 @@ class DispatchReport:
             lines.append("join index cache:")
             for d in self.index_decisions:
                 if d.fired:
-                    lines.append(f"  + {d.node} -> cached index")
+                    lines.append(f"  + {d.node} -> cached index "
+                                 f"({d.mode} probe)")
                 else:
                     lines.append(f"  - {d.node} -> in-program argsort "
                                  f"({d.reason})")

@@ -83,7 +83,8 @@ def _dispatch_lines(report) -> List[str]:
         lines.append(f"{verdict:<9}{d.pattern:<22}{d.node}  [{why}]")
     for d in getattr(report, "index_decisions", ()):
         verdict = "indexed" if d.fired else "inline"
-        lines.append(f"{verdict:<9}{d.pattern:<22}{d.node}  [{d.reason}]")
+        why = d.mode if d.fired else d.reason
+        lines.append(f"{verdict:<9}{d.pattern:<22}{d.node}  [{why}]")
     return lines
 
 

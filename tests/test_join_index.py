@@ -13,7 +13,11 @@ Acceptance surface of the IndexCache subsystem:
 * the dispatch report names which joins probe the cache vs rebuild,
 * safety: a false ``Field.unique`` declaration fails loudly at index
   build; undeclared filtered build sides fall back to in-program sort,
-* a hypothesis property test over adversarial duplicate/absent keys.
+* a hypothesis property test over adversarial duplicate/absent keys,
+* the slot-table probe of a dense key domain: equal to the binary
+  search and to NumPy for every join kind, chosen by the 8-slots-per-
+  row rule, counted, replicated by the parallel engine and served in
+  vmapped batches.
 """
 import numpy as np
 import pytest
@@ -21,7 +25,12 @@ import pytest
 from conftest import assert_results_equal
 from repro.core import CompileCache, FlareContext, col, count, sum_
 from repro.core import engines as ENG
+from repro.core import lower as L
+from repro.core import plan as P
+from repro.core import stages as S
+from repro.obs import metrics as OM
 from repro.relational import queries as Q
+from repro.relational import table as T
 from repro.relational.table import Table
 
 SF = 0.005
@@ -268,3 +277,233 @@ print("PLACED_OK")
 # The adversarial duplicate/absent-key hypothesis property test lives in
 # tests/test_property.py (test_join_index_cache_adversarial_keys), with
 # the other optional-dep property tests.
+
+
+# ---------------------------------------------------------------------------
+# the slot-table probe of a dense key domain (direct joins)
+# ---------------------------------------------------------------------------
+
+_DOM = 64  # per-key domain of the operator-level cases
+
+
+def _probe_case(case):
+    """(probe key columns, probe mask, build key columns, build mask,
+    per-key domains) of one operator-level case."""
+    rng = np.random.default_rng(7)
+    n_probe, n_build = 300, 40
+    pmask = rng.random(n_probe) < 0.9
+    if case == "composite":
+        doms = (8, 8)
+        flat = rng.choice(64, n_build, replace=False)
+        build = [flat // 8, flat % 8]
+        probe = [rng.integers(0, 8, n_probe), rng.integers(0, 8, n_probe)]
+        return probe, pmask, build, None, doms
+    kb = rng.choice(_DOM, n_build, replace=False)
+    kp = rng.integers(0, _DOM, n_probe)
+    bmask = None
+    if case == "duplicates":
+        kb[10:20] = kb[:10]  # each of ten keys held by two rows
+    elif case == "filtered":
+        bmask = rng.random(n_build) < 0.6
+    elif case == "out_of_range":
+        kp[::3] = rng.integers(-2 * _DOM, 0, len(kp[::3]))
+        kp[1::5] = rng.integers(_DOM, 3 * _DOM, len(kp[1::5]))
+    return [kp], pmask, [kb], bmask, (_DOM,)
+
+
+def _streams(case):
+    probe, pmask, build, bmask, doms = _probe_case(case)
+    lnames = [f"pk{i}" for i in range(len(probe))]
+    rnames = [f"k{i}" for i in range(len(build))]
+
+    def stream(names, keys, mask, extra):
+        cols = {n: np.asarray(k, np.int32) for n, k in zip(names, keys)}
+        cols.update(extra)
+        info = L.StaticInfo(
+            {n: L.StaticCol(T.INT32, None, d) for n, d in zip(names, doms)}
+            | {n: L.StaticCol(T.FLOAT32) for n in extra},
+            len(keys[0]))
+        return L.Stream(cols, None if mask is None else np.asarray(mask),
+                        info)
+
+    n_build = len(build[0])
+    left = stream(lnames, probe, pmask,
+                  {"x": np.arange(len(probe[0]), dtype=np.float32)})
+    right = stream(rnames, build, bmask,
+                   {"v": 1.0 + np.arange(n_build, dtype=np.float32)})
+    kb = np.zeros(n_build, np.int64)
+    for k, d in zip(build, doms):
+        kb = kb * d + k
+    perm = np.argsort(kb, kind="stable").astype(np.int32)
+    index = ENG.JoinIndex(perm, kb[perm].astype(np.int32), False)
+    return left, right, lnames, rnames, index, doms
+
+
+def _numpy_join(case, how):
+    """Per probe row: matched, and the first matching build row's v."""
+    probe, pmask, build, bmask, doms = _probe_case(case)
+    kp = np.zeros(len(probe[0]), np.int64)
+    for k, d in zip(probe, doms):
+        kp = kp * d + k
+    kb = np.zeros(len(build[0]), np.int64)
+    for k, d in zip(build, doms):
+        kb = kb * d + k
+    matched = np.zeros(len(kp), bool)
+    v = np.zeros(len(kp), np.float32)
+    for i, key in enumerate(kp):
+        rows = np.flatnonzero(kb == key)
+        if len(rows) and pmask[i]:
+            row = rows[0]  # the first row among duplicates
+            if bmask is None or bmask[row]:
+                matched[i], v[i] = True, 1.0 + row
+    return matched, v
+
+
+_CASES = ["unique", "filtered", "duplicates", "out_of_range", "composite"]
+
+
+@pytest.mark.parametrize("case", _CASES)
+@pytest.mark.parametrize("how", ["inner", "left", "semi", "anti"])
+def test_direct_probe_matches_sorted_and_numpy(how, case):
+    """The slot-table probe gives the binary search's match mask and
+    probe mask bit for bit, its build row wherever a row matched, and
+    NumPy's answer for every join kind."""
+    left, right, lnames, rnames, index, doms = _streams(case)
+    join = P.Join(P.Scan("probe"), P.Scan("build"), tuple(lnames),
+                  tuple(rnames), how)
+    slots = index.slot_table(int(np.prod(doms)))
+    sorted_ = L._join_probe(join, left, right,
+                            (index.perm, index.keys, None))
+    direct = L._join_probe(join, left, right,
+                           (index.perm, index.keys, slots))
+    np.testing.assert_array_equal(direct[1], sorted_[1])
+    np.testing.assert_array_equal(direct[2], sorted_[2])
+    hit = np.asarray(direct[1])
+    np.testing.assert_array_equal(np.asarray(direct[0])[hit],
+                                  np.asarray(sorted_[0])[hit])
+
+    out = L._lower_join(join, left, right, None,
+                        (index.perm, index.keys, slots))
+    want_matched, want_v = _numpy_join(case, how)
+    pmask = np.asarray(left.mask)
+    want_mask = {"inner": want_matched, "left": pmask,
+                 "semi": want_matched,
+                 "anti": pmask & ~want_matched}[how]
+    np.testing.assert_array_equal(np.asarray(out.mask), want_mask)
+    if how in ("inner", "left"):
+        got_v = np.asarray(out.cols["v"])
+        np.testing.assert_array_equal(got_v[want_mask],
+                                      want_v[want_mask])
+
+
+def test_slot_table_first_row_wins_and_rejects_keys_outside_domain():
+    perm = np.array([1, 3, 0, 2], np.int32)  # keys 2, 5, 5, 9 sorted
+    keys = np.array([2, 5, 5, 9], np.int32)
+    slots = np.asarray(ENG.JoinIndex(perm, keys, False).slot_table(12))
+    want = np.full(12, -1, np.int32)
+    want[[2, 5, 9]] = [1, 0, 2]  # key 5: rows 3 and 0, the lowest wins
+    np.testing.assert_array_equal(slots, want)
+    with pytest.raises(ValueError, match="declared domain"):
+        ENG.JoinIndex(perm, keys, False).slot_table(9)
+
+
+@pytest.mark.parametrize("domain,n_build,declared,mode", [
+    (16, 8, True, "direct"),     # 2 slots a row
+    (64, 8, True, "direct"),     # exactly 8 slots a row
+    (72, 8, True, "sorted"),     # over 8: sparse
+    (16, 8, False, "sorted"),    # no declared domain
+])
+def test_probe_form_follows_declared_domain(domain, n_build, declared,
+                                            mode):
+    """Dense declared key domains probe the slot table; a domain of
+    more than 8 slots per build row, or none at all, keeps the binary
+    search -- and both answer like the oracle."""
+    c = FlareContext()
+    rng = np.random.default_rng(3)
+    c.from_arrays("probe", {
+        "pk": rng.integers(0, domain, 50).astype(np.int32),
+        "x": rng.uniform(0, 10, 50),
+    }, domains={"pk": domain} if declared else None)
+    c.from_arrays("build", {
+        "k": rng.choice(domain, n_build, replace=False).astype(np.int32),
+        "v": np.arange(n_build, dtype=np.float64),
+    }, domains={"k": domain} if declared else None, uniques=["k"])
+    q = (c.table("probe")
+         .join(c.table("build"), on="pk", right_on="k", how="left")
+         .sort("pk", "x"))
+    lowered = q.lower(engine="compiled")
+    (decision,) = lowered.dispatch_report().joins_cached
+    assert decision.mode == mode
+    before = OM.REGISTRY.get(f"join.probe.{mode}")
+    got = lowered.compile(cache=CompileCache())()
+    assert OM.REGISTRY.get(f"join.probe.{mode}") == before + 1
+    assert_results_equal(q.collect(engine="volcano"), got, msg=mode)
+
+
+_STREAM_JOINS = {"q3": 2, "q5": 5, "q10": 3, "q14": 1, "q19": 1, "q22": 1}
+
+
+@pytest.fixture(scope="module")
+def ctx01():
+    c = FlareContext()
+    Q.register_tpch(c, sf=0.01)
+    return c
+
+
+@pytest.mark.parametrize("name", sorted(_STREAM_JOINS))
+def test_stream_joins_all_probe_direct(ctx01, name):
+    """Every cached join of the power stream's queries is direct: the
+    dispatch report names the form and the lowering counts it; none
+    takes the binary search."""
+    lowered = getattr(Q, name)(ctx01).lower(engine="compiled")
+    rep = lowered.dispatch_report()
+    assert [d.mode for d in rep.joins_cached] == \
+        ["direct"] * _STREAM_JOINS[name]
+    assert "direct probe" in str(rep)
+    before = {m: OM.REGISTRY.get(f"join.probe.{m}")
+              for m in ("direct", "sorted")}
+    lowered.compile(cache=CompileCache())
+    assert OM.REGISTRY.get("join.probe.direct") - before["direct"] == \
+        _STREAM_JOINS[name]
+    assert OM.REGISTRY.get("join.probe.sorted") == before["sorted"]
+
+
+def test_preload_builds_no_slot_table():
+    c = FlareContext()
+    Q.register_tpch(c, sf=SF)
+    c.preload()
+    assert len(c.cache.indexes) > 0
+    assert all(not e.slots for e in c.cache.indexes._entries.values())
+
+
+@pytest.mark.parametrize("name", ["q3", "q10"])
+def test_parallel_engine_replicates_slot_tables(ctx, name):
+    q = getattr(Q, name)(ctx)
+    lowered = q.lower(engine="parallel")
+    assert all(d.mode == "direct"
+               for d in lowered.dispatch_report().joins_cached)
+    assert_results_equal(q.collect(engine="volcano"), lowered.compile()(),
+                         msg=f"{name} parallel direct")
+    specs, _ = L.join_index_plan(lowered._plan, ctx.catalog)
+    placed = {k[1] for k in ctx.cache._cache if k[0] == "placed"}
+    for spec in specs.values():
+        slots = S.index_args((spec,), ctx.catalog, ctx.cache)[2]
+        assert id(slots) in placed, spec
+
+
+@pytest.mark.parametrize("tname", ["q14", "q19"])
+def test_served_join_template_batches_probe_direct(ctx, tname):
+    """A vmapped batch of a join template probes the slot table once
+    for all bindings and answers each like its own execution."""
+    tmpl = Q.TEMPLATES[tname](ctx)
+    lowered = tmpl.lower(engine="compiled")
+    assert [d.mode for d in lowered.dispatch_report().joins_cached] == \
+        ["direct"]
+    compiled = lowered.compile()
+    bindings = [dict(b) for b in Q.TEMPLATE_BINDINGS[tname]]
+    batched = compiled.batch(bindings)
+    for b, got in zip(bindings, batched):
+        assert_results_equal(compiled.result(**b).compact(),
+                             got.compact(), msg=f"{tname} {b}")
+        assert_results_equal(tmpl.collect(engine="volcano", params=b),
+                             got.compact(), msg=f"{tname} {b} volcano")
